@@ -55,8 +55,8 @@ def run() -> dict:
     # verify's per-position cost vs the single-token baseline.
     bsz, nb_ = 16, 256
     n_pool = 8 * nb_ + 1
-    kpp = jnp.asarray(rng.standard_normal((n_pool, bsz, kv, d)), jnp.float32)
-    vpp = jnp.asarray(rng.standard_normal((n_pool, bsz, kv, d)), jnp.float32)
+    kpp = jnp.asarray(rng.standard_normal((kv, n_pool, bsz, d)), jnp.float32)
+    vpp = jnp.asarray(rng.standard_normal((kv, n_pool, bsz, d)), jnp.float32)
     btp = jnp.asarray(
         1 + rng.permutation(n_pool - 1)[:8 * nb_].reshape(8, nb_), jnp.int32)
     klp = jnp.full((8,), nb_ * bsz, jnp.int32)

@@ -178,7 +178,7 @@ PY
 
 fleet_smoke() {
     echo "== fleet smoke (2 models x 2 tiers, model-aware routing, v6 metrics) =="
-    python -m repro.launch.serve --arch chatglm2-6b \
+    python -m repro.launch.serve --reduced --arch chatglm2-6b \
         --models "chatglm2-6b:0.6,qwen2-1.5b:0.4" --requests 32 \
         --replicas 2 --router slo_aware --fleet joint \
         --metrics-json /tmp/fleet_m.json > /dev/null
@@ -205,7 +205,7 @@ PY
 
 traced_smoke() {
     echo "== traced smoke (serve.py --paged --trace/--metrics-json) =="
-    python -m repro.launch.serve --paged --preempt --speculate \
+    python -m repro.launch.serve --reduced --paged --preempt --speculate \
         --chunk-tokens 8 --requests 8 \
         --trace /tmp/trace.json --metrics-json /tmp/m.json > /dev/null
     python - <<'PY'
@@ -232,10 +232,10 @@ PY
 
 profile_smoke() {
     echo "== profile smoke (--profile-out / --profile-in round trip) =="
-    python -m repro.launch.serve --paged --speculate --chunk-tokens 8 \
-        --requests 8 --profile-out /tmp/prof.json > /tmp/serve_a.log
-    python -m repro.launch.serve --paged --speculate --chunk-tokens 8 \
-        --requests 8 --profile-in /tmp/prof.json > /tmp/serve_b.log
+    python -m repro.launch.serve --reduced --paged --speculate \
+        --chunk-tokens 8 --requests 8 --profile-out /tmp/prof.json > /tmp/serve_a.log
+    python -m repro.launch.serve --reduced --paged --speculate \
+        --chunk-tokens 8 --requests 8 --profile-in /tmp/prof.json > /tmp/serve_b.log
     da=$(grep -o 'outputs_digest=[0-9a-f]*' /tmp/serve_a.log)
     db=$(grep -o 'outputs_digest=[0-9a-f]*' /tmp/serve_b.log)
     if [[ -z "$da" || "$da" != "$db" ]]; then

@@ -6,9 +6,10 @@
   (possibly sequence-sharded) KV cache.
 * ``wkv6`` — RWKV-6 chunked recurrence with data-dependent decay.
 
-``ops.py`` is the jit'd dispatching wrapper (backend = 'xla' | 'pallas' |
-'pallas_interpret' | 'naive'); ``ref.py`` is the pure-jnp oracle used by the
-allclose test sweeps.  The TPU kernels are validated on CPU via
-``interpret=True``.
+``ops.py`` is the dispatching wrapper: Pallas on the TPU, blocked XLA
+elsewhere (``backend.get_backend``); ``ref.py`` is the pure-jnp oracle used
+by the allclose test sweeps.  On CPU the kernels are validated with
+``interpret=True`` and compiled for a described TPU v5e in
+``tests/test_tpu_compile.py``.
 """
-from repro.kernels.backend import get_backend, set_backend, use_backend  # noqa: F401
+from repro.kernels.backend import get_backend, use_backend  # noqa: F401

@@ -1,36 +1,43 @@
-"""Global kernel-backend selection.
+"""Kernel-backend selection.
 
-'xla'              — blocked pure-JAX implementations (CPU + dry-run default;
-                     also a solid TPU fallback).
-'pallas'           — pl.pallas_call compiled for TPU (the deployment target).
+The backend follows the platform: ``'pallas'`` (``pl.pallas_call`` compiled
+by the TPU's compiler) where ``jax.default_backend() == "tpu"``, ``'xla'``
+(blocked pure-JAX implementations) everywhere else.  ``use_backend`` picks
+another one for a scope — tests and parity checks only:
+
 'pallas_interpret' — kernel body interpreted on CPU (correctness validation).
-'naive'            — the ref.py oracle (tests, tiny shapes only).
+'naive'            — the ref.py oracle (tiny shapes only).
+'xla'              — on the TPU, the reference the kernels are checked
+                     against.
+
+The choice is read while a function is traced, so a jitted function keeps
+the backend it was first traced under.
 """
 from __future__ import annotations
 
 import contextlib
+from typing import Optional
 
-_BACKEND = "xla"
+import jax
+
 VALID = ("xla", "pallas", "pallas_interpret", "naive")
-
-
-def set_backend(name: str) -> None:
-    global _BACKEND
-    if name not in VALID:
-        raise ValueError(f"backend {name!r} not in {VALID}")
-    _BACKEND = name
+_override: Optional[str] = None
 
 
 def get_backend() -> str:
-    return _BACKEND
+    if _override is not None:
+        return _override
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
 @contextlib.contextmanager
 def use_backend(name: str):
-    global _BACKEND
-    prev = _BACKEND
-    set_backend(name)
+    global _override
+    if name not in VALID:
+        raise ValueError(f"backend {name!r} not in {VALID}")
+    prev = _override
+    _override = name
     try:
         yield
     finally:
-        _BACKEND = prev
+        _override = prev

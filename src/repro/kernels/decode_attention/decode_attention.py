@@ -1,11 +1,16 @@
 """Flash-decoding Pallas TPU kernel: one query token per sequence attends to a
 long KV cache, blocked along the sequence axis.
 
-Grid (batch, kv_head, kv_blocks), kv innermost; the G query heads that share a
-kv head form the matmul rows ([G, d] x [d, kv_block] -> [G, kv_block]), padded
-to the 8-sublane minimum.  Running (m, l, acc) stay in VMEM scratch across the
-kv sweep.  Per-sequence valid lengths arrive via scalar prefetch so fully
-masked tail blocks are skipped without recompilation.
+Grid (batch, kv_head, kv_blocks), kv innermost; the G query heads that share
+a kv head form the matmul rows ([G, d] x [d, kv_block] -> [G, kv_block]),
+padded to the 8-sublane minimum.  Running (m, l, acc) stay in VMEM scratch
+across the kv sweep.  Per-sequence valid lengths arrive via scalar prefetch
+so fully masked tail blocks are skipped without recompilation.
+
+The kernel reads a head-major ``[B, KV, S, D]`` cache: the TPU compiler tiles
+the last two block dimensions by (8, 128) unless they span the whole array,
+so the head axis cannot be one of them.  The wrapper transposes the
+``[B, S, KV, D]`` cache in, one copy of the whole cache per call.
 """
 from __future__ import annotations
 
@@ -41,7 +46,7 @@ def _dec_kernel(kv_len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     @pl.when(run)
     def _compute():
         q = q_ref[0, 0, :, :].astype(jnp.float32) * scale      # [g_pad, d]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)              # [kvb, d]
+        k = k_ref[0, 0, :, :].astype(jnp.float32)              # [kvb, d]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if softcap is not None:
@@ -58,7 +63,7 @@ def _dec_kernel(kv_len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         corr = jnp.exp(m_prev - m_new)
         l_ref[:, :1] = l_ref[:, :1] * corr + p.sum(axis=-1, keepdims=True)
         m_ref[:, :1] = m_new
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        v = v_ref[0, 0, :, :].astype(jnp.float32)
         pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         acc_ref[...] = acc_ref[...] * corr + pv
@@ -96,6 +101,7 @@ def decode_attention_pallas(
         k = jnp.pad(k, ((0, 0), (0, s_p - s), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, s_p - s), (0, 0), (0, 0)))
     nk = s_p // kv_block
+    k, v = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)    # [B, KV, S, D]
 
     qg = q.reshape(b, kv, group, d)
     if g_pad != group:
@@ -110,8 +116,8 @@ def decode_attention_pallas(
         grid=(b, kv, nk),
         in_specs=[
             pl.BlockSpec((1, 1, g_pad, d), lambda bi, hi, ki, kvl: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, kv_block, 1, d), lambda bi, hi, ki, kvl: (bi, ki, hi, 0)),
-            pl.BlockSpec((1, kv_block, 1, dv), lambda bi, hi, ki, kvl: (bi, ki, hi, 0)),
+            pl.BlockSpec((1, 1, kv_block, d), lambda bi, hi, ki, kvl: (bi, hi, ki, 0)),
+            pl.BlockSpec((1, 1, kv_block, dv), lambda bi, hi, ki, kvl: (bi, hi, ki, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g_pad, dv), lambda bi, hi, ki, kvl: (bi, hi, 0, 0)),
         scratch_shapes=[

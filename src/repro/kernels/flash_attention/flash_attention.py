@@ -11,6 +11,12 @@ Causal masking skips fully-masked kv blocks via pl.when; the diagonal block
 applies an iota mask.  Sliding-window and Gemma-style softcap are supported so
 the same kernel serves llama/qwen (full causal), gemma2 (window + softcap) and
 whisper's encoder (bidirectional: causal=False).
+
+The kernel reads head-major ``[B, H, S, D]`` tiles: the TPU compiler tiles
+the last two block dimensions by (8, 128) unless they span the whole array,
+so the head axis cannot be one of them.  The wrapper transposes the
+``[B, S, H, D]`` activations in and out, one copy of q, k, v and the output
+per call.
 """
 from __future__ import annotations
 
@@ -51,8 +57,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale          # [qb, d]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)                  # [kvb, d]
+        q = q_ref[0, 0, :, :].astype(jnp.float32) * scale          # [qb, d]
+        k = k_ref[0, 0, :, :].astype(jnp.float32)                  # [kvb, d]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # [qb, kvb]
         if softcap is not None:
@@ -73,7 +79,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         corr = jnp.exp(m_prev - m_new)
         l_ref[:, :1] = l_ref[:, :1] * corr + p.sum(axis=-1, keepdims=True)
         m_ref[:, :1] = m_new
-        v = v_ref[0, :, 0, :].astype(jnp.float32)                   # [kvb, dv]
+        v = v_ref[0, 0, :, :].astype(jnp.float32)                   # [kvb, dv]
         pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         acc_ref[...] = acc_ref[...] * corr + pv
@@ -81,7 +87,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     @pl.when(ki == nk - 1)
     def _finalize():
         out = acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
+        o_ref[0, 0, :, :] = out.astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -118,6 +124,7 @@ def flash_attention_pallas(
         k = jnp.pad(k, ((0, 0), (0, skv_p - skv), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, skv_p - skv), (0, 0), (0, 0)))
     nq, nk = sq_p // q_block, skv_p // kv_block
+    q, k, v = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))     # [B, H, S, D]
 
     kernel = functools.partial(
         _fa_kernel, scale=scale, causal=causal, window=window, softcap=softcap,
@@ -128,12 +135,12 @@ def flash_attention_pallas(
         kernel,
         grid=(b, h, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, q_block, 1, d), lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-            pl.BlockSpec((1, kv_block, 1, d), lambda bi, hi, qi, ki: (bi, ki, hi // group, 0)),
-            pl.BlockSpec((1, kv_block, 1, dv), lambda bi, hi, qi, ki: (bi, ki, hi // group, 0)),
+            pl.BlockSpec((1, 1, q_block, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, 1, kv_block, d), lambda bi, hi, qi, ki: (bi, hi // group, ki, 0)),
+            pl.BlockSpec((1, 1, kv_block, dv), lambda bi, hi, qi, ki: (bi, hi // group, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, q_block, 1, dv), lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sq_p, h, dv), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, q_block, dv), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, sq_p, dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((q_block, 128), jnp.float32),   # running max m
             pltpu.VMEM((q_block, 128), jnp.float32),   # running sum l
@@ -141,4 +148,4 @@ def flash_attention_pallas(
         ],
         interpret=interpret,
     )(q, k, v)
-    return out[:, :sq]
+    return jnp.swapaxes(out, 1, 2)[:, :sq]

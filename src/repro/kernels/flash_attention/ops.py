@@ -1,6 +1,7 @@
 """Dispatching wrapper for flash attention: picks the backend
-(naive oracle / blocked-XLA / Pallas TPU / Pallas-interpret) from the global
-kernel-backend setting.  This is the symbol the model layers import.
+(naive oracle / blocked-XLA / Pallas TPU / Pallas-interpret) from
+``kernels.backend.get_backend`` — Pallas on the TPU, XLA elsewhere.  This is
+the symbol the model layers import.
 """
 from __future__ import annotations
 

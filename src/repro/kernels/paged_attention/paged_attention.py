@@ -11,8 +11,11 @@ Two entry points share one kernel body:
   absolute offsets ``kv_len .. kv_len+T-1`` and are causally masked against
   the paged history *and each other* (query t sees positions ``<= kv_len+t``).
 
-Grid (batch, kv_head, logical_block); the K/V BlockSpec index maps read the
-block table via scalar prefetch — ``(bt[b, i], 0, h, 0)`` — so the DMA engine
+Pools are head-major, ``[KV, N, bs, D]``: the TPU compiler tiles the last
+two dimensions of a block by (8, 128) unless they span the whole array, so
+the kv-head axis must not be one of them.  Grid (batch, kv_head,
+logical_block); the K/V BlockSpec ``(1, 1, bs, D)`` index maps read the block
+table via scalar prefetch — ``(h, bt[b, i], 0, 0)`` — so the DMA engine
 fetches exactly the physical block that logical slot ``i`` of sequence ``b``
 owns.  No contiguous copy of the cache ever exists: this is the PagedAttention
 memory model with the flash-decoding online softmax of
@@ -102,7 +105,7 @@ def _paged_kernel(kv_len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(k_start < base + t_span)
     def _compute():
         q = q_ref[0, 0, :, :].astype(jnp.float32) * scale      # [rows, d]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)              # [bs, d]
+        k = k_ref[0, 0, :, :].astype(jnp.float32)              # [bs, d]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if softcap is not None:
@@ -120,7 +123,7 @@ def _paged_kernel(kv_len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
         corr = jnp.exp(m_prev - m_new)
         l_ref[:, :1] = l_ref[:, :1] * corr + p.sum(axis=-1, keepdims=True)
         m_ref[:, :1] = m_new
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        v = v_ref[0, 0, :, :].astype(jnp.float32)
         pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         acc_ref[...] = acc_ref[...] * corr + pv
@@ -136,8 +139,8 @@ def _paged_kernel(kv_len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
     static_argnames=("t_span", "group", "softcap", "scale", "interpret"))
 def _paged_window_core(
     q: jnp.ndarray,              # [B, T, H, D]
-    k_pool: jnp.ndarray,         # [N, bs, KV, D]
-    v_pool: jnp.ndarray,         # [N, bs, KV, Dv]
+    k_pool: jnp.ndarray,         # [KV, N, bs, D]
+    v_pool: jnp.ndarray,         # [KV, N, bs, Dv]
     block_tables: jnp.ndarray,   # [B, nb] int32 (pre-bucketed by the wrapper)
     kv_len: jnp.ndarray,         # [B] int32 — history BEFORE the window
     *,
@@ -148,7 +151,7 @@ def _paged_window_core(
     interpret: bool,
 ) -> jnp.ndarray:
     b, t, h, d = q.shape
-    _, bs, kv, dv = v_pool.shape
+    kv, _, bs, dv = v_pool.shape
     nb = block_tables.shape[1]
     scale = scale if scale is not None else d ** -0.5
     gp = _group_pad(t, group)
@@ -172,10 +175,10 @@ def _paged_window_core(
         in_specs=[
             pl.BlockSpec((1, 1, rows, d),
                          lambda bi, hi, ki, kvl, bt: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda bi, hi, ki, kvl, bt: (bt[bi, ki], 0, hi, 0)),
-            pl.BlockSpec((1, bs, 1, dv),
-                         lambda bi, hi, ki, kvl, bt: (bt[bi, ki], 0, hi, 0)),
+            pl.BlockSpec((1, 1, bs, d),
+                         lambda bi, hi, ki, kvl, bt: (hi, bt[bi, ki], 0, 0)),
+            pl.BlockSpec((1, 1, bs, dv),
+                         lambda bi, hi, ki, kvl, bt: (hi, bt[bi, ki], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, rows, dv),
                                lambda bi, hi, ki, kvl, bt: (bi, hi, 0, 0)),
@@ -198,8 +201,8 @@ def _paged_window_core(
 
 def paged_window_attention_pallas(
     q: jnp.ndarray,              # [B, T, H, D] — the draft window
-    k_pool: jnp.ndarray,         # [N, bs, KV, D]
-    v_pool: jnp.ndarray,         # [N, bs, KV, Dv]
+    k_pool: jnp.ndarray,         # [KV, N, bs, D]
+    v_pool: jnp.ndarray,         # [KV, N, bs, Dv]
     block_tables: jnp.ndarray,   # [B, nb] int32
     kv_len: jnp.ndarray,         # [B] int32 — history length BEFORE the window
     *,
@@ -210,7 +213,7 @@ def paged_window_attention_pallas(
     """Multi-token paged attention: window position t (absolute ``kv_len+t``,
     K/V already scattered at ``kv_len .. kv_len+T-1``) attends to cache
     positions ``<= kv_len + t``.  Returns [B, T, H, Dv]."""
-    group = q.shape[2] // k_pool.shape[2]
+    group = q.shape[2] // k_pool.shape[0]
     return _paged_window_core(
         q, k_pool, v_pool, _pad_tables(block_tables),
         jnp.asarray(kv_len, jnp.int32), t_span=q.shape[1], group=group,
@@ -219,8 +222,8 @@ def paged_window_attention_pallas(
 
 def paged_decode_attention_pallas(
     q: jnp.ndarray,              # [B, H, D]
-    k_pool: jnp.ndarray,         # [N, bs, KV, D]
-    v_pool: jnp.ndarray,         # [N, bs, KV, Dv]
+    k_pool: jnp.ndarray,         # [KV, N, bs, D]
+    v_pool: jnp.ndarray,         # [KV, N, bs, Dv]
     block_tables: jnp.ndarray,   # [B, nb] int32 (pad rows with a valid block)
     kv_len: jnp.ndarray,         # [B] int32 — valid entries incl. the query
     *,
@@ -230,7 +233,7 @@ def paged_decode_attention_pallas(
 ) -> jnp.ndarray:
     """Single-token paged decode: the query sits at position ``kv_len - 1``
     (its K/V already scattered), i.e. the T=1 window at base ``kv_len - 1``."""
-    group = q.shape[1] // k_pool.shape[2]
+    group = q.shape[1] // k_pool.shape[0]
     out = _paged_window_core(
         q[:, None], k_pool, v_pool, _pad_tables(block_tables),
         jnp.asarray(kv_len, jnp.int32) - 1, t_span=1, group=group,

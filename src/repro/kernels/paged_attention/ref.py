@@ -11,16 +11,18 @@ from repro.kernels.decode_attention.ref import decode_attention_reference
 
 
 def gather_pool(pool: jnp.ndarray, block_tables: jnp.ndarray) -> jnp.ndarray:
-    """[N, bs, KV, d] pool + [B, nb] table -> contiguous [B, nb*bs, KV, d]."""
+    """Head-major [KV, N, bs, d] pool + [B, nb] table -> contiguous
+    [B, nb*bs, KV, d]."""
     b, nb = block_tables.shape
-    _, bs, kv, d = pool.shape
-    return pool[block_tables].reshape(b, nb * bs, kv, d)
+    kv, _, bs, d = pool.shape
+    g = pool[:, block_tables]                       # [KV, B, nb, bs, d]
+    return jnp.moveaxis(g.reshape(kv, b, nb * bs, d), 0, 2)
 
 
 def paged_decode_attention_reference(
     q: jnp.ndarray,              # [B, H, D]  (one new token)
-    k_pool: jnp.ndarray,         # [N, bs, KV, D]   paged K pool
-    v_pool: jnp.ndarray,         # [N, bs, KV, Dv]
+    k_pool: jnp.ndarray,         # [KV, N, bs, D]   paged K pool
+    v_pool: jnp.ndarray,         # [KV, N, bs, Dv]
     block_tables: jnp.ndarray,   # [B, nb] int32 — physical block per logical slot
     kv_len: jnp.ndarray,         # [B] int32 — valid cache entries per sequence
     *,
@@ -35,8 +37,8 @@ def paged_decode_attention_reference(
 
 def paged_window_attention_reference(
     q: jnp.ndarray,              # [B, T, H, D] — draft window
-    k_pool: jnp.ndarray,         # [N, bs, KV, D]
-    v_pool: jnp.ndarray,         # [N, bs, KV, Dv]
+    k_pool: jnp.ndarray,         # [KV, N, bs, D]
+    v_pool: jnp.ndarray,         # [KV, N, bs, Dv]
     block_tables: jnp.ndarray,   # [B, nb] int32
     kv_len: jnp.ndarray,         # [B] int32 — history length BEFORE the window
     *,

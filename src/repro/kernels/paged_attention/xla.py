@@ -1,7 +1,9 @@
 """Paged decode attention in plain XLA: one dense gather through the block
 table materializes the contiguous view, then the same masked partial-softmax
-math as decode_attention_xla.  CPU + dry-run default and the TPU fallback —
-the Pallas kernel avoids the materialized gather entirely.
+math as decode_attention_xla.  The path off the TPU (CPU, dry-run) and the
+reference the Pallas kernel is checked against on the chip — the kernel
+avoids the materialized gather entirely.  Pools are head-major
+``[KV, N, bs, D]`` (see paged_attention.py).
 """
 from __future__ import annotations
 
@@ -14,11 +16,19 @@ import jax.numpy as jnp
 from repro.kernels.decode_attention.xla import decode_attention_partial
 
 
+def _gather(pool: jnp.ndarray, block_tables: jnp.ndarray) -> jnp.ndarray:
+    """[KV, N, bs, d] pool through a [B, nb] table -> [B, nb*bs, KV, d]."""
+    kv, _, bs, d = pool.shape
+    b, nb = block_tables.shape
+    g = pool[:, block_tables].reshape(kv, b, nb * bs, d)
+    return jnp.transpose(g, (1, 2, 0, 3))
+
+
 @functools.partial(jax.jit, static_argnames=("softcap", "scale"))
 def paged_window_attention_xla(
     q: jnp.ndarray,              # [B, T, H, D] — draft window
-    k_pool: jnp.ndarray,         # [N, bs, KV, D]
-    v_pool: jnp.ndarray,         # [N, bs, KV, Dv]
+    k_pool: jnp.ndarray,         # [KV, N, bs, D]
+    v_pool: jnp.ndarray,         # [KV, N, bs, Dv]
     block_tables: jnp.ndarray,   # [B, nb] int32
     kv_len: jnp.ndarray,         # [B] int32 — history length BEFORE the window
     *,
@@ -30,11 +40,9 @@ def paged_window_attention_xla(
     partial-softmax math as the single-token step (unrolled over the static
     T) — identical per-position shapes keep verify logits bitwise equal to
     sequential decode on CPU, which greedy token-identity rides on."""
-    b, t, h, d = q.shape
-    _, bs, kv, dv = v_pool.shape
-    nb = block_tables.shape[1]
-    k = k_pool[block_tables].reshape(b, nb * bs, kv, -1)
-    v = v_pool[block_tables].reshape(b, nb * bs, kv, dv)
+    t = q.shape[1]
+    k = _gather(k_pool, block_tables)
+    v = _gather(v_pool, block_tables)
     outs = []
     for ti in range(t):
         acc, m, l = decode_attention_partial(
@@ -46,18 +54,16 @@ def paged_window_attention_xla(
 @functools.partial(jax.jit, static_argnames=("softcap", "scale"))
 def paged_decode_attention_xla(
     q: jnp.ndarray,              # [B, H, D]
-    k_pool: jnp.ndarray,         # [N, bs, KV, D]
-    v_pool: jnp.ndarray,         # [N, bs, KV, Dv]
+    k_pool: jnp.ndarray,         # [KV, N, bs, D]
+    v_pool: jnp.ndarray,         # [KV, N, bs, Dv]
     block_tables: jnp.ndarray,   # [B, nb] int32
     kv_len: jnp.ndarray,         # [B] int32
     *,
     softcap: Optional[float] = None,
     scale: Optional[float] = None,
 ) -> jnp.ndarray:
-    b, nb = block_tables.shape
-    _, bs, kv, dv = v_pool.shape
-    k = k_pool[block_tables].reshape(b, nb * bs, kv, -1)
-    v = v_pool[block_tables].reshape(b, nb * bs, kv, dv)
+    k = _gather(k_pool, block_tables)
+    v = _gather(v_pool, block_tables)
     acc, m, l = decode_attention_partial(q, k, v, kv_len, softcap=softcap,
                                          scale=scale)
     out = acc / jnp.maximum(l, 1e-30)[..., None]

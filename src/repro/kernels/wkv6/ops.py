@@ -17,8 +17,5 @@ def wkv6(r, k, v, w, u, s0=None, *, chunk: int = 32):
         return wkv6_reference(r, k, v, w, u, s0)
     if backend == "xla":
         return wkv6_xla(r, k, v, w, u, s0, chunk=chunk)
-    if s0 is not None:
-        # Pallas path starts from zero state; fold a nonzero s0 via the xla path.
-        return wkv6_xla(r, k, v, w, u, s0, chunk=chunk)
-    return wkv6_pallas(r, k, v, w, u, chunk=chunk,
+    return wkv6_pallas(r, k, v, w, u, s0, chunk=chunk,
                        interpret=(backend == "pallas_interpret"))

@@ -32,7 +32,6 @@ from repro.launch.mesh import make_production_mesh, mesh_shape_dict
 from repro.models import api
 from repro.models.transformer import group_period
 from repro.perf.cost_model import step_cost
-from repro.sharding import compat
 from repro.sharding.plan import ShardingPlan
 from repro.sharding.specs import cache_specs_tree, param_specs
 from repro.training import OptConfig, TrainConfig, init_opt_state, \
@@ -151,7 +150,7 @@ def lower_cell(cfg: ModelConfig, shape: ShapeConfig, *, multi_pod: bool,
         return jax.tree.map(lambda s: NamedSharding(mesh, s), spec_tree,
                             is_leaf=lambda s: isinstance(s, P))
 
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         pspecs = param_specs(cfg, plan, params_struct, mshape)
         batch_axes = plan.batch_axes if len(plan.batch_axes) > 1 else \
             (plan.batch_axes[0] if plan.batch_axes else None)
@@ -219,7 +218,7 @@ def lower_cell(cfg: ModelConfig, shape: ShapeConfig, *, multi_pod: bool,
 
         mem = compiled.memory_analysis()
         record["memory_analysis"] = _mem_dict(mem)
-        ca = compat.cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis()
         record["hlo_flops"] = float(ca.get("flops", 0.0))
         record["hlo_bytes"] = float(ca.get("bytes accessed", 0.0))
 

@@ -17,7 +17,6 @@ import jax
 from repro.configs import SHAPES, get_config
 from repro.launch import dryrun as dr
 from repro.perf.cost_model import step_cost
-from repro.sharding import compat
 
 ART = pathlib.Path(__file__).resolve().parents[3] / "artifacts" / "perf"
 
@@ -146,7 +145,7 @@ def measure_int8_cache(cfg, shape, mp, tag):
         return jax.tree.map(lambda s: NamedSharding(mesh, s), t,
                             is_leaf=lambda s: isinstance(s, P))
 
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         pspecs = param_specs(cfg, plan, params_struct, mshape)
         cspecs = cache_specs_tree(cfg, plan, cache_struct, mshape)
         ba = plan.batch_axes[0]
@@ -177,7 +176,7 @@ def measure_int8_cache(cfg, shape, mp, tag):
         compiled = lowered.compile()
         rec["compile_s"] = round(time.perf_counter() - t0, 2)
         rec["memory_analysis"] = dr._mem_dict(compiled.memory_analysis())
-        ca = compat.cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis()
         rec["hlo_flops"] = float(ca.get("flops", 0.0))
         rec["hlo_bytes"] = float(ca.get("bytes accessed", 0.0))
         from repro.models.transformer import group_period

@@ -11,9 +11,8 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    # Auto axis_types is make_mesh's default on jax>=0.6 and the only
-    # behaviour on 0.4.x (which has no AxisType) — don't pass it explicitly.
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         (jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def mesh_shape_dict(mesh) -> dict[str, int]:

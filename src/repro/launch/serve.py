@@ -3,6 +3,12 @@
   PYTHONPATH=src python -m repro.launch.serve --arch smollm-135m --reduced \
       --requests 12 --scheduler slo-odbs
 
+The model runs at its published widths; ``--reduced`` cuts it to the toy
+widths a CPU can serve (and prompts to 16/32 tokens).  Paged decode runs the
+Pallas kernels on a TPU and the blocked-XLA paths elsewhere
+(``kernels.backend``).  JAX's persistent compilation cache lives where
+``JAX_COMPILATION_CACHE_DIR`` says, else in ``<repo>/.jax_cache``.
+
 ``--paged`` serves through the paged continuous-batching runtime instead
 (block-table KV, per-prompt prefill, allocator-gated admission); the pool is
 sized from ``--kv-budget`` bytes — the same budget surface SLO-ODBS uses.
@@ -40,14 +46,17 @@ pools with model-aware routing, and ``--fleet`` picks between one joint
 allocator over the shared replica budget (marginal SLO value, model-swap
 actions) and independent per-pool autoscalers.
 
-On a TPU pod this runs under the production mesh with the HELR-mesh plan;
-on CPU (--reduced) it serves the reduced config end-to-end.
+With ``--paged --replicas N`` replica i's params and KV pool live on
+``jax.devices()[i % len(jax.devices())]`` — one chip per replica on a
+multi-chip host, all on the one device elsewhere.
 """
 from __future__ import annotations
 
 import argparse
 import hashlib
 import json
+import os
+import pathlib
 import time
 
 import jax
@@ -73,6 +82,28 @@ from repro.serving import (AutoscalerConfig, EngineConfig, FaultEvent,
                            PagedEngineConfig, Replica, RetryConfig, Router,
                            RouterConfig, get_drafter, paper_cluster,
                            simulate_cluster)
+
+
+# engine shape shared by every paged path (single engine and replicas)
+MAX_BATCH = 4
+BLOCK_SIZE = 8
+CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def configure_compile_cache() -> None:
+    """Keep JAX's persistent compilation cache at a fixed path so a later
+    process finds what an earlier one compiled.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it and nothing is
+    changed here."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
+
+
+def _max_seq(reqs, max_new: int) -> int:
+    """Block-table width: the longest prompt plus the decode budget, so any
+    --max-new value is admissible."""
+    max_prompt = max(len(r.tokens) for r in reqs)
+    return max(64, -(-(max_prompt + max_new) // BLOCK_SIZE) * BLOCK_SIZE)
 
 
 def _parse_model_mix(spec: str) -> list:
@@ -185,28 +216,31 @@ def _write_artifacts(args, mon, tracer, cprof, *, latency_s=None,
 
 
 def _serve_cluster_live(args, cfg, params, mon, reqs, tracer, cprof,
-                        cal_models) -> dict:
+                        cal_models, devices) -> tuple[dict, list]:
     """Route requests across N real PagedEngine-backed replicas, then serve
-    each replica's share live (per-replica pool + prefix cache)."""
-    max_prompt = max(len(r.tokens) for r in reqs)
-    max_seq = max(64, -(-(max_prompt + args.max_new) // 8) * 8)
+    each replica's share live (per-replica pool + prefix cache).  Replica i
+    holds its params and pool on ``devices[i % len(devices)]``; every jitted
+    step follows those committed inputs.  Returns (outputs, engines)."""
+    max_seq = _max_seq(reqs, args.max_new)
     router = Router(RouterConfig(policy=args.router))
     replicas = []
     for i in range(args.replicas):
         nodes, lat = paper_cluster()
+        dev = devices[i % len(devices)]
         pcfg = PagedEngineConfig.from_memory_budget(
-            cfg, args.kv_budget, max_batch=4, block_size=8,
+            cfg, args.kv_budget, max_batch=MAX_BATCH, block_size=BLOCK_SIZE,
             max_seq_len=max_seq, max_new_tokens=args.max_new,
             prefix_cache=args.prefix_cache, admit_lookahead=args.lookahead,
             chunk_tokens=args.chunk_tokens, preempt=args.preempt,
             spec_tokens=args.spec_tokens, drafter=args.drafter)
         rep = Replica(
-            i, cfg, nodes, lat, max_batch=4, block_size=8,
+            i, cfg, nodes, lat, max_batch=MAX_BATCH, block_size=BLOCK_SIZE,
             n_blocks=pcfg.usable_blocks, prefix_cache=args.prefix_cache,
             chunk_tokens=args.chunk_tokens, preempt=args.preempt,
             spec_tokens=args.spec_tokens,
             spec_acceptance=_spec_acceptance(args, cprof),
-            engine=PagedEngine(cfg, params, pcfg, monitor=mon,
+            engine=PagedEngine(cfg, jax.device_put(params, dev), pcfg,
+                               monitor=mon,
                                drafter=_make_drafter(args, cfg),
                                tracer=tracer, track=i,
                                cost_profiler=cprof),
@@ -243,12 +277,13 @@ def _serve_cluster_live(args, cfg, params, mon, reqs, tracer, cprof,
         spec = "" if not args.spec_tokens else (
             f", spec acc={res.acceptance_rate:.2f} "
             f"it/tok={res.iterations_per_token:.2f}")
-        print(f"replica {rep.rid}: {len(rep.queue)} requests, "
+        print(f"replica {rep.rid} on {rep.engine.device}: "
+              f"{len(rep.queue)} requests, "
               f"prefill_tokens={res.prefill_tokens}, "
               f"prefix_hits={res.prefix_hits}/{res.prefix_lookups}, "
               f"peak_blocks={res.peak_blocks}{spec}")
     print(f"router: {router.stats.summary()}")
-    return done
+    return done, [rep.engine for rep in replicas]
 
 
 def _serve_cluster_sim(args, prof, mon, tracer, cprof, cal_models) -> None:
@@ -360,10 +395,16 @@ def _serve_cluster_sim(args, prof, mon, tracer, cprof, cal_models) -> None:
               f"saved={s['prefill_tokens_saved']}")
 
 
-def main():
+def main(argv=None) -> dict:
+    """Serve once from the command line ``argv`` (``sys.argv[1:]`` when
+    None).  Returns ``{"outputs": rid -> generated tokens, "engines": the
+    PagedEngines that served}`` (both empty on the simulated cluster)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=False,
+                    help="toy widths (configs.base.reduced) and 16/32-token "
+                         "prompts, for a CPU; off: published widths")
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--scheduler", default="slo-odbs",
                     choices=["slo-odbs", "slo-dbs", "odbs", "fifo", "s3"])
@@ -482,7 +523,8 @@ def main():
                          "bounded memory) so a throttled/migrated replica "
                          "re-learns; 0 = never forget.  Ignored with "
                          "--profile-in (the registry's setting wins)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    configure_compile_cache()
     if args.pricing_quantile is not None \
             and not 0.0 < args.pricing_quantile <= 1.0:
         raise SystemExit("--pricing-quantile must be in (0, 1]")
@@ -517,7 +559,7 @@ def main():
 
     if args.chunk_tokens < 0:
         args.chunk_tokens = derive_chunk_tokens(SchedulerConfig(),
-                                                block_size=8)
+                                                block_size=BLOCK_SIZE)
         print(f"chunk budget from scheduler threshold: "
               f"{args.chunk_tokens} tokens/iteration")
 
@@ -540,30 +582,32 @@ def main():
         _serve_cluster_sim(args, prof, mon, tracer, cprof, cal_models)
         print("monitor:", mon.metrics())
         _write_artifacts(args, mon, tracer, cprof, cal_models=cal_models)
-        return
+        return {"outputs": {}, "engines": []}
 
-    params = api.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
-    engine = InferenceEngine(cfg, params,
-                             EngineConfig(max_batch=4, cache_len=64,
-                                          max_new_tokens=args.max_new))
+    # jitted so no eager temporaries sit beside the weights on the device
+    params = jax.jit(lambda key: api.init_params(cfg, key, jnp.float32))(
+        jax.random.PRNGKey(0))
 
     if args.workload == "shared-prefix":
         reqs = gen_shared_prefix_requests(SharedPrefixConfig(
             n_requests=args.requests, n_templates=max(2, args.requests // 6),
             prefix_len=16, suffix_mean=2.0, vocab=cfg.vocab_size, seed=0))
-        for r in reqs:
-            r.tokens = [t % cfg.vocab_size for t in r.tokens[:32]]
+        if args.reduced:
+            for r in reqs:
+                r.tokens = [t % cfg.vocab_size for t in r.tokens[:32]]
     else:
         pattern = args.workload if args.workload in ("bursty", "diurnal") \
             else "poisson"
         reqs = gen_requests(WorkloadConfig(n_requests=args.requests, seed=0,
                                            vocab=cfg.vocab_size,
                                            arrival_pattern=pattern))
-        for r in reqs:
-            r.tokens = [t % cfg.vocab_size for t in r.tokens[:16]]
+        if args.reduced:
+            for r in reqs:
+                r.tokens = [t % cfg.vocab_size for t in r.tokens[:16]]
     for r in reqs:
         r.input_len = len(r.tokens)
         r.true_output_len = r.true_output_len % args.max_new + 1
+    max_seq = _max_seq(reqs, args.max_new)
 
     pred = LengthPredictor(PredictorConfig(vocab=cfg.vocab_size), seed=0)
     toks, lens = train_pairs(WorkloadConfig(vocab=cfg.vocab_size), 256, seed=1)
@@ -574,16 +618,14 @@ def main():
     prof.profile(reqs)
 
     t0 = time.perf_counter()
+    engines: list = []
     if args.replicas > 1 and args.paged:
-        done = _serve_cluster_live(args, cfg, params, mon, reqs, tracer,
-                                   cprof, cal_models)
+        done, engines = _serve_cluster_live(args, cfg, params, mon, reqs,
+                                            tracer, cprof, cal_models,
+                                            jax.devices())
     elif args.paged:
-        # size the block tables for the longest admitted prompt plus the
-        # decode budget so any --max-new value is admissible
-        max_prompt = max(len(r.tokens) for r in reqs)
-        max_seq = max(64, -(-(max_prompt + args.max_new) // 8) * 8)
         pcfg = PagedEngineConfig.from_memory_budget(
-            cfg, args.kv_budget, max_batch=4, block_size=8,
+            cfg, args.kv_budget, max_batch=MAX_BATCH, block_size=BLOCK_SIZE,
             max_seq_len=max_seq, max_new_tokens=args.max_new,
             prefix_cache=args.prefix_cache,
             admit_lookahead=args.lookahead,
@@ -598,6 +640,7 @@ def main():
         paged = PagedEngine(cfg, params, pcfg, monitor=mon,
                             drafter=_make_drafter(args, cfg), tracer=tracer,
                             cost_profiler=cprof)
+        engines = [paged]
         res = paged.run_continuous(sorted(reqs, key=lambda r: r.arrival))
         done = res.outputs
         print(f"paged: {res.admission_waves} admission waves, "
@@ -623,21 +666,28 @@ def main():
                   f"cow_forks={res.cow_forks}, "
                   f"evictions={res.prefix_evictions}, "
                   f"peak_residents={res.peak_residents}")
-    elif args.continuous:
-        res = engine.run_continuous(sorted(reqs, key=lambda r: r.arrival))
-        done = res.outputs
     else:
-        done = {}
-        for b in get_scheduler(args.scheduler)(reqs, SchedulerConfig(max_batch=4)):
-            res = engine.run_batch(b, true_lens={r.rid: r.true_output_len
-                                                 for r in b.requests})
-            done.update(res.outputs)
-            for r in b.requests:
-                mon.observe(r)
+        engine = InferenceEngine(cfg, params, EngineConfig(
+            max_batch=MAX_BATCH, cache_len=max_seq,
+            max_new_tokens=args.max_new))
+        if args.continuous:
+            res = engine.run_continuous(
+                sorted(reqs, key=lambda r: r.arrival))
+            done = res.outputs
+        else:
+            done = {}
+            for b in get_scheduler(args.scheduler)(
+                    reqs, SchedulerConfig(max_batch=MAX_BATCH)):
+                res = engine.run_batch(
+                    b, true_lens={r.rid: r.true_output_len
+                                  for r in b.requests})
+                done.update(res.outputs)
+                for r in b.requests:
+                    mon.observe(r)
     dt = time.perf_counter() - t0
     total = sum(len(v) for v in done.values())
     print(f"served {len(done)} requests, {total} tokens in {dt:.2f}s "
-          f"({total/dt:.1f} tok/s on CPU)")
+          f"({total/dt:.1f} tok/s on {jax.devices()[0].platform})")
     print(f"outputs_digest={_outputs_digest(done)}")
     if args.spec_tokens and cprof.spec_samples:
         print(f"measured acceptance EMA: {cprof.spec_acceptance:.3f} "
@@ -646,6 +696,7 @@ def main():
     print("monitor:", mon.metrics())
     _write_artifacts(args, mon, tracer, cprof, throughput=total / dt,
                      cal_models=cal_models)
+    return {"outputs": done, "engines": engines}
 
 
 if __name__ == "__main__":

@@ -92,12 +92,14 @@ def paged_compatible(cfg: ModelConfig) -> tuple[bool, str]:
 
 
 def init_paged_pools(cfg: ModelConfig, n_blocks: int, block_size: int,
-                     dtype=jnp.float32):
+                     dtype=jnp.float32, device=None):
     """Zero-initialized paged K/V pools mirroring the decode-cache tree:
-    {"l{i}": {"mixer": {"k": [n_groups, n_blocks, bs, KV, hd], "v": ...}}} —
-    the same stacked layer-group layout lax.scan consumes, with the per-
-    sequence (b, s) axes replaced by the physical (n_blocks, block_size)
-    pool axes shared by every sequence."""
+    {"l{i}": {"mixer": {"k": [n_groups, KV, n_blocks, bs, hd], "v": ...}}} —
+    the stacked layer-group layout lax.scan consumes, with the per-sequence
+    (b, s) axes replaced by the physical (n_blocks, block_size) pool axes
+    shared by every sequence.  Head-major, so one (head, block) tile is a
+    contiguous [bs, hd] slab the paged kernel DMAs whole.  ``device``
+    commits the pools to that device (default: JAX's default device)."""
     ok, why = paged_compatible(cfg)
     if not ok:
         raise ValueError(f"{cfg.name}: {why}")
@@ -108,8 +110,10 @@ def init_paged_pools(cfg: ModelConfig, n_blocks: int, block_size: int,
     pools = {}
     for i in range(period):
         pools[f"l{i}"] = {"mixer": {
-            "k": jnp.zeros((n_groups, n_blocks, block_size, kv, hd), dtype),
-            "v": jnp.zeros((n_groups, n_blocks, block_size, kv, dv), dtype),
+            "k": jnp.zeros((n_groups, kv, n_blocks, block_size, hd), dtype,
+                           device=device),
+            "v": jnp.zeros((n_groups, kv, n_blocks, block_size, dv), dtype,
+                           device=device),
         }}
     return pools
 

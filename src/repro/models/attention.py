@@ -26,7 +26,6 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.paged_attention import (paged_decode_attention,
                                            paged_window_attention)
 from repro.models.common import apply_dense, apply_mrope, apply_rope, dense_init
-from repro.sharding.compat import get_abstract_mesh, shard_map
 from repro.sharding.plan import ShardingPlan, axis_size, constrain, divisible
 
 # --------------------------------------------------------------------- init
@@ -112,7 +111,7 @@ def _head_spec(plan: Optional[ShardingPlan], n_kv: int):
 def _seq_parallel_prefill(cfg, plan, q, k, v, *, causal, window, softcap):
     """shard_map context-parallel flash attention: q sharded on seq over the
     model axis, K/V replicated (gathered once)."""
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     ax = plan.model_axis
     batch = plan.batch_axes if len(plan.batch_axes) != 1 else plan.batch_axes[0]
     s_loc = q.shape[1] // axis_size(ax)
@@ -122,7 +121,7 @@ def _seq_parallel_prefill(cfg, plan, q, k, v, *, causal, window, softcap):
         return flash_attention(qs, ks, vs, causal=causal, window=window,
                                softcap=softcap, q_offset=idx * s_loc)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(batch, ax, None, None), P(batch, None, None, None),
                   P(batch, None, None, None)),
@@ -132,7 +131,7 @@ def _seq_parallel_prefill(cfg, plan, q, k, v, *, causal, window, softcap):
 
 def _sharded_decode(cfg, plan, q, k_cache, v_cache, kv_len, *, softcap, window):
     """flash-decoding: KV cache sequence-sharded over plan.seq_axes."""
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     axes = plan.seq_axes
     batch = plan.batch_axes if len(plan.batch_axes) != 1 else plan.batch_axes[0]
     n_shards = axis_size(axes)
@@ -165,7 +164,7 @@ def _sharded_decode(cfg, plan, q, k_cache, v_cache, kv_len, *, softcap, window):
         return (jax.lax.psum(acc * w[..., None], a),
                 m_max, jax.lax.psum(l * w, a))
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(batch, None, None), P(batch, ax_tuple, None, None),
                   P(batch, ax_tuple, None, None), P(batch)),
@@ -352,9 +351,9 @@ def attn_paged_decode(cfg: ModelConfig, spec: LayerSpec, p, x, pool,
                       plan: Optional[ShardingPlan] = None):
     """One-token decode against a *paged* KV pool.
 
-    x: [B, 1, d]; pool: {"k": [N, bs, KV, hd], "v": [N, bs, KV, dv]} — one
-    layer's physical block pool; block_tables: [B, nb] int32 (rows padded
-    with a valid null block); kv_len: [B] current lengths.  The new token's
+    x: [B, 1, d]; pool: {"k": [KV, N, bs, hd], "v": [KV, N, bs, dv]} — one
+    layer's head-major physical block pool; block_tables: [B, nb] int32
+    (rows padded with a valid null block); kv_len: [B] current lengths.  The new token's
     K/V is scattered into slot ``kv_len`` of its sequence's block table, then
     attention reads the cache through the table (kernels.paged_attention).
     Returns (y, updated pool).  MLA and sliding-window layers keep their
@@ -374,11 +373,11 @@ def attn_paged_decode(cfg: ModelConfig, spec: LayerSpec, p, x, pool,
     if cfg.rope == "mrope":
         positions = jnp.broadcast_to(positions, (3, b, 1))
     q, k, v = _qkv(cfg, p, x, positions)
-    bs = pool["k"].shape[1]
+    bs = pool["k"].shape[2]
     blk = block_tables[jnp.arange(b), kv_len // bs]          # [B] physical ids
     off = kv_len % bs
-    k_pool = pool["k"].at[blk, off].set(k[:, 0])
-    v_pool = pool["v"].at[blk, off].set(v[:, 0])
+    k_pool = pool["k"].at[:, blk, off].set(jnp.swapaxes(k[:, 0], 0, 1))
+    v_pool = pool["v"].at[:, blk, off].set(jnp.swapaxes(v[:, 0], 0, 1))
     out = paged_decode_attention(q[:, 0], k_pool, v_pool, block_tables,
                                  kv_len + 1, softcap=cfg.attn_softcap)
     y = apply_dense(p["o"], out.reshape(b, -1))
@@ -412,8 +411,8 @@ def attn_paged_spec(cfg: ModelConfig, spec: LayerSpec, p, x, pool,
     if cfg.rope == "mrope":
         positions = jnp.broadcast_to(positions, (3, b, t))
     q, k, v = _qkv(cfg, p, x, positions)
-    k_pool = pool["k"].at[blk, off].set(k)
-    v_pool = pool["v"].at[blk, off].set(v)
+    k_pool = pool["k"].at[:, blk, off].set(jnp.moveaxis(k, 2, 0))
+    v_pool = pool["v"].at[:, blk, off].set(jnp.moveaxis(v, 2, 0))
     out = paged_window_attention(q, k_pool, v_pool, block_tables, kv_len,
                                  softcap=cfg.attn_softcap)
     y = apply_dense(p["o"], out.reshape(b, t, -1))

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models.mlp import mlp_init
 from repro.models.common import activation, dense_init
-from repro.sharding.compat import get_abstract_mesh, pvary, shard_map
 from repro.sharding.plan import ShardingPlan
 
 
@@ -56,7 +55,7 @@ def moe_apply(cfg: ModelConfig, p, x, *, plan: Optional[ShardingPlan] = None):
     communication is the single psum over the model axis that dense TP would
     also pay.  Without a mesh it is the same code, locally."""
     if plan is not None and plan.batch_axes:
-        mesh = get_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         if mesh is not None and not mesh.empty:
             return _moe_sharded(cfg, p, x, plan, mesh)
     y, aux = _moe_local(cfg, p, x, psum_axis=None)
@@ -93,7 +92,7 @@ def _moe_sharded(cfg: ModelConfig, p, x, plan: ShardingPlan, mesh):
             aux = jax.tree.map(lambda a: jax.lax.pmean(a, all_axes), aux)
         return y, aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs,
         out_specs=(P(batch, None, None), {"lb_loss": P(), "drop_frac": P()}),
     )(p, x)
@@ -170,7 +169,7 @@ def _moe_local(cfg: ModelConfig, p, x, *, psum_axis, ep_axis=None,
 
     if ep_axis is not None:
         if ep_pvary:
-            buf = pvary(buf, (ep_axis,))
+            buf = jax.lax.pcast(buf, (ep_axis,), to="varying")
         # exchange dispatch buffers: [E, C, d] -> [E/ep, ep*C, d]
         buf = jax.lax.all_to_all(buf, ep_axis, split_axis=0, concat_axis=1,
                                  tiled=True)

@@ -181,9 +181,11 @@ def init_params(cfg: ModelConfig, key, dtype=None):
             sub[f"l{i}"] = block_init(cfg, plan_specs[i], gkeys[i], dtype)
         return sub
 
-    gkeys = jax.random.split(k_blocks, n_groups)
-    groups = [init_group(gkeys[g]) for g in range(n_groups)]
-    params["blocks"] = jax.tree.map(lambda *xs: jnp.stack(xs), *groups)
+    # vmapped over the group keys: the stacked [n_groups, ...] weights are
+    # built directly (bitwise equal to stacking per-group inits, without a
+    # second copy) and a jitted init compiles one group, not every layer
+    params["blocks"] = jax.vmap(init_group)(
+        jax.random.split(k_blocks, n_groups))
     return params
 
 

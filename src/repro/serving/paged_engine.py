@@ -210,8 +210,9 @@ class PagedDecodeState:
 
     @classmethod
     def create(cls, cfg: ModelConfig, pcfg: PagedEngineConfig,
-               dtype=jnp.float32) -> "PagedDecodeState":
-        pools = api.init_paged_pools(cfg, pcfg.n_blocks, pcfg.block_size, dtype)
+               dtype=jnp.float32, device=None) -> "PagedDecodeState":
+        pools = api.init_paged_pools(cfg, pcfg.n_blocks, pcfg.block_size,
+                                     dtype, device)
         alloc = BlockAllocator(pcfg.n_blocks)
         null = alloc.alloc(-1, 1)[0]             # reserved garbage block
         b, nb = pcfg.max_batch, pcfg.max_blocks
@@ -310,6 +311,10 @@ class PagedEngine:
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self.track = track
         self.dtype = dtype
+        # the pools live where the params do (one engine per device), and
+        # every jitted step follows its committed inputs there
+        devs = jax.tree.leaves(params)[0].devices()
+        self.device = next(iter(devs)) if len(devs) == 1 else None
         # speculative decoding: drafter + the one-pass verify step scoring
         # the K drafts and the current input token together
         self.drafter = None
@@ -355,15 +360,16 @@ class PagedEngine:
         # layer pools in place (src/dst are scalars, donated pools alias)
         self._cow_copy = jax.jit(
             lambda pools, src, dst: jax.tree.map(
-                lambda p: p.at[:, dst].set(p[:, src]), pools),
+                lambda p: p.at[:, :, dst].set(p[:, :, src]), pools),
             donate_argnums=(0,))
 
     @staticmethod
     def _scatter_impl(pools, cache, blk, off):
         """Write a b=1 prefill cache (leaves [n_groups, 1, cl, KV, hd]) into
-        the pools at (blk[t], off[t]) — one scatter per layer leaf."""
+        the head-major pools ([n_groups, KV, N, bs, hd]) at (blk[t], off[t])
+        — one scatter per layer leaf."""
         def write(pool, c):
-            return pool.at[:, blk, off].set(c[:, 0])
+            return pool.at[:, :, blk, off].set(jnp.swapaxes(c[:, 0], 1, 2))
         return jax.tree.map(write, pools, cache)
 
     # --------------------------------------------------------------- admission
@@ -580,9 +586,9 @@ class PagedEngine:
         idx = jnp.asarray(blocks, jnp.int32)
 
         def g(pool):
-            sel = pool[:, idx]                  # [n_groups, nb, bs, KV, hd]
-            flat = sel.reshape(sel.shape[0], -1, *sel.shape[3:])
-            return flat[:, None, :p_len]
+            sel = pool[:, :, idx]               # [n_groups, KV, nb, bs, hd]
+            flat = sel.reshape(*sel.shape[:2], -1, sel.shape[-1])
+            return jnp.swapaxes(flat[:, :, :p_len], 1, 2)[:, None]
         return jax.tree.map(g, pools)
 
     # ---------------------------------------------------------------- prefill
@@ -864,7 +870,8 @@ class PagedEngine:
                 raise ValueError(
                     f"request {r.rid}: needs {wb} blocks, pool has "
                     f"{self.pcfg.usable_blocks} usable")
-        st = PagedDecodeState.create(self.cfg, self.pcfg, self.dtype)
+        st = PagedDecodeState.create(self.cfg, self.pcfg, self.dtype,
+                                     self.device)
         queue = list(requests)
         outs: dict[int, list[int]] = {}
         if resume:

@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.sharding.compat import get_abstract_mesh
 
 
 @dataclass(frozen=True)
@@ -32,7 +31,7 @@ class ShardingPlan:
 
 
 def _mesh_axis_sizes() -> dict[str, int]:
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
         return {}
     return dict(mesh.shape)

@@ -52,13 +52,13 @@ def check(arch: str) -> float:
     dec_ref, _ = api.decode_step(cfg, params, nxt, cache_ref, kv_len)
 
     # sharded: mesh (data=2, model=4)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         (jax.sharding.AxisType.Auto,) * 2)
     plan = ShardingPlan(batch_axes=("data",), model_axis="model",
                         ep_axis="data" if cfg.moe is not None else None,
                         seq_axes=("model",), remat=False)
     mshape = dict(zip(mesh.axis_names, mesh.devices.shape))
-    from repro.sharding.compat import set_mesh
-    with set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         pspecs = param_specs(cfg, plan, params, mshape)
         sh = lambda t: jax.tree.map(lambda s: NamedSharding(mesh, s), t,
                                     is_leaf=lambda s: isinstance(s, P))

@@ -19,9 +19,8 @@ from repro.perf.cost_model import ParallelismDesc, step_cost
 
 
 def _hlo_flops(fn, *args):
-    from repro.sharding.compat import cost_analysis_dict
     c = jax.jit(fn).lower(*args).compile()
-    return float(cost_analysis_dict(c).get("flops", 0.0))
+    return float(c.cost_analysis().get("flops", 0.0))
 
 
 @pytest.mark.parametrize("arch", ["smollm-135m", "qwen2-moe-a2.7b", "rwkv6-3b"])

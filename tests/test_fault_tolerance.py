@@ -74,13 +74,12 @@ def test_dp_mean_compressed_single_device():
     from jax.sharding import PartitionSpec as P
     from repro.training.grad_compress import dp_mean_compressed
 
-    mesh = jax.make_mesh((1,), ("dp",))
+    mesh = jax.make_mesh((1,), ("dp",), (jax.sharding.AxisType.Auto,))
     g = {"w": jnp.ones((8, 8)) * 0.5}
 
     def f(grads):
         return dp_mean_compressed(grads, "dp")
 
-    from repro.sharding.compat import shard_map
-    out = jax.jit(shard_map(f, mesh=mesh, in_specs=({"w": P()},),
+    out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=({"w": P()},),
                             out_specs={"w": P()}, check_vma=False))(g)
     np.testing.assert_allclose(np.asarray(out["w"]), 0.5, atol=5e-3)

@@ -76,3 +76,19 @@ def test_wkv6_carried_state(rng):
                                np.asarray(o_full), atol=2e-5, rtol=2e-4)
     np.testing.assert_allclose(np.asarray(s2), np.asarray(s_full), atol=2e-5,
                                rtol=2e-4)
+
+
+def test_wkv6_pallas_carried_state(rng):
+    """The kernel starts from a carried state s0 (no fallback to the XLA
+    path): two halves with the state handed over == one shot."""
+    b, t, h, d, dv = 1, 64, 2, 16, 16
+    r, k, v, w, u = _gen(rng, b, t, h, d, dv)
+    o_full, s_full = wkv6_reference(r, k, v, w, u)
+    o1, s1 = wkv6_pallas(r[:, :32], k[:, :32], v[:, :32], w[:, :32], u,
+                         chunk=16, interpret=True)
+    o2, s2 = wkv6_pallas(r[:, 32:], k[:, 32:], v[:, 32:], w[:, 32:], u, s1,
+                         chunk=16, interpret=True)
+    np.testing.assert_allclose(np.asarray(jnp.concatenate([o1, o2], 1)),
+                               np.asarray(o_full), atol=5e-5, rtol=5e-4)
+    np.testing.assert_allclose(np.asarray(s2), np.asarray(s_full), atol=5e-5,
+                               rtol=5e-4)

@@ -1,5 +1,5 @@
-"""Paged decode-attention kernels: block-table gather parity against the
-contiguous decode oracle, across the xla / pallas-interpret backends, with
+"""Paged decode-attention kernels over head-major ``[KV, N, bs, D]`` pools:
+block-table gather parity against the contiguous decode oracle, across the xla / pallas-interpret backends, with
 padded (null-block) table tails; multi-token window parity (speculative
 verification) and the power-of-two block-table bucketing that caps jit
 specialization churn."""
@@ -29,8 +29,8 @@ CASES = [
 def _mk(rng, case):
     b, h, kv, d, bs, nb, n, cap = case
     q = rng.standard_normal((b, h, d)).astype(np.float32)
-    kp = rng.standard_normal((n, bs, kv, d)).astype(np.float32)
-    vp = rng.standard_normal((n, bs, kv, d)).astype(np.float32)
+    kp = rng.standard_normal((kv, n, bs, d)).astype(np.float32)
+    vp = rng.standard_normal((kv, n, bs, d)).astype(np.float32)
     bt = rng.permutation(n)[:b * nb].reshape(b, nb).astype(np.int32)
     kv_len = rng.integers(1, nb * bs + 1, size=b).astype(np.int32)
     ref = decode_attention_reference(
@@ -63,8 +63,8 @@ def test_padded_table_tail_is_inert(rng, impl):
     must not leak into the output."""
     b, h, kv, d, bs, nb, n = 2, 4, 2, 16, 8, 4, 16
     q = rng.standard_normal((b, h, d)).astype(np.float32)
-    kp = rng.standard_normal((n, bs, kv, d)).astype(np.float32)
-    vp = rng.standard_normal((n, bs, kv, d)).astype(np.float32)
+    kp = rng.standard_normal((kv, n, bs, d)).astype(np.float32)
+    vp = rng.standard_normal((kv, n, bs, d)).astype(np.float32)
     bt = (1 + rng.permutation(n - 1)[:b * nb].reshape(b, nb)).astype(np.int32)
     kv_len = np.array([bs + 3, 2 * bs], np.int32)   # <= 2 blocks valid
     fn = paged_decode_attention_xla if impl == "xla" else (
@@ -74,8 +74,8 @@ def test_padded_table_tail_is_inert(rng, impl):
     bt2 = bt.copy()
     bt2[:, 2:] = 0
     kp2, vp2 = kp.copy(), vp.copy()
-    kp2[0] = 1e3
-    vp2[0] = -1e3
+    kp2[:, 0] = 1e3
+    vp2[:, 0] = -1e3
     out2 = np.asarray(fn(q, kp2, vp2, jnp.asarray(bt2), jnp.asarray(kv_len)))
     np.testing.assert_allclose(out1, out2, atol=2e-5, rtol=2e-5)
 
@@ -94,8 +94,8 @@ WINDOW_CASES = [
 def _mk_window(rng, case, t):
     b, h, kv, d, bs, nb, n, cap = case
     q = rng.standard_normal((b, t, h, d)).astype(np.float32)
-    kp = rng.standard_normal((n, bs, kv, d)).astype(np.float32)
-    vp = rng.standard_normal((n, bs, kv, d)).astype(np.float32)
+    kp = rng.standard_normal((kv, n, bs, d)).astype(np.float32)
+    vp = rng.standard_normal((kv, n, bs, d)).astype(np.float32)
     bt = rng.permutation(n)[:b * nb].reshape(b, nb).astype(np.int32)
     # ragged histories: every sequence a different base length, window fits
     base = rng.integers(0, nb * bs - t + 1, size=b).astype(np.int32)
@@ -127,8 +127,8 @@ def test_window_t1_reproduces_single_token_kernel(rng, case):
     kernel — same core, same row layout, bitwise."""
     b, h, kv, d, bs, nb, n, cap = case
     q = rng.standard_normal((b, h, d)).astype(np.float32)
-    kp = rng.standard_normal((n, bs, kv, d)).astype(np.float32)
-    vp = rng.standard_normal((n, bs, kv, d)).astype(np.float32)
+    kp = rng.standard_normal((kv, n, bs, d)).astype(np.float32)
+    vp = rng.standard_normal((kv, n, bs, d)).astype(np.float32)
     bt = rng.permutation(n)[:b * nb].reshape(b, nb).astype(np.int32)
     kv_len = rng.integers(1, nb * bs + 1, size=b).astype(np.int32)
     single = paged_decode_attention_pallas(
@@ -145,8 +145,8 @@ def test_window_causality_within_window(rng):
     later draft's K/V cannot change an earlier position's output."""
     b, h, kv, d, bs, nb, n, t = 1, 4, 2, 16, 8, 3, 12, 4
     q = rng.standard_normal((b, t, h, d)).astype(np.float32)
-    kp = rng.standard_normal((n, bs, kv, d)).astype(np.float32)
-    vp = rng.standard_normal((n, bs, kv, d)).astype(np.float32)
+    kp = rng.standard_normal((kv, n, bs, d)).astype(np.float32)
+    vp = rng.standard_normal((kv, n, bs, d)).astype(np.float32)
     bt = rng.permutation(n)[:nb].reshape(1, nb).astype(np.int32)
     base = np.array([5], np.int32)
     out1 = np.asarray(paged_window_attention_xla(
@@ -154,8 +154,8 @@ def test_window_causality_within_window(rng):
     # scramble the *last* window position's K/V slot (logical pos base+t-1)
     pos = int(base[0]) + t - 1
     kp2, vp2 = kp.copy(), vp.copy()
-    kp2[bt[0, pos // bs], pos % bs] = 1e3
-    vp2[bt[0, pos // bs], pos % bs] = -1e3
+    kp2[:, bt[0, pos // bs], pos % bs] = 1e3
+    vp2[:, bt[0, pos // bs], pos % bs] = -1e3
     out2 = np.asarray(paged_window_attention_xla(
         q, kp2, vp2, jnp.asarray(bt), jnp.asarray(base)))
     np.testing.assert_array_equal(out1[:, :t - 1], out2[:, :t - 1])
@@ -166,8 +166,8 @@ def test_window_causality_within_window(rng):
 def test_window_padded_table_tail_is_inert(rng, t):
     b, h, kv, d, bs, nb, n = 2, 4, 2, 16, 8, 4, 16
     q = rng.standard_normal((b, t, h, d)).astype(np.float32)
-    kp = rng.standard_normal((n, bs, kv, d)).astype(np.float32)
-    vp = rng.standard_normal((n, bs, kv, d)).astype(np.float32)
+    kp = rng.standard_normal((kv, n, bs, d)).astype(np.float32)
+    vp = rng.standard_normal((kv, n, bs, d)).astype(np.float32)
     bt = (1 + rng.permutation(n - 1)[:b * nb].reshape(b, nb)).astype(np.int32)
     base = np.array([bs + 3 - t, 2 * bs - t], np.int32)
     out1 = np.asarray(paged_window_attention_pallas(
@@ -175,8 +175,8 @@ def test_window_padded_table_tail_is_inert(rng, t):
     bt2 = bt.copy()
     bt2[:, 2:] = 0
     kp2, vp2 = kp.copy(), vp.copy()
-    kp2[0] = 1e3
-    vp2[0] = -1e3
+    kp2[:, 0] = 1e3
+    vp2[:, 0] = -1e3
     out2 = np.asarray(paged_window_attention_pallas(
         q, kp2, vp2, jnp.asarray(bt2), jnp.asarray(base), interpret=True))
     np.testing.assert_allclose(out1, out2, atol=2e-5, rtol=2e-5)
@@ -190,8 +190,8 @@ def test_block_table_width_buckets_cap_compiles(rng):
     without this the kernel respecializes per distinct nb."""
     b, h, kv, d, bs, n = 2, 4, 2, 16, 8, 64
     q = rng.standard_normal((b, h, d)).astype(np.float32)
-    kp = rng.standard_normal((n, bs, kv, d)).astype(np.float32)
-    vp = rng.standard_normal((n, bs, kv, d)).astype(np.float32)
+    kp = rng.standard_normal((kv, n, bs, d)).astype(np.float32)
+    vp = rng.standard_normal((kv, n, bs, d)).astype(np.float32)
     outs = {}
     before = _paged_window_core._cache_size()
     for nb in (5, 6, 7, 8):
@@ -226,12 +226,12 @@ def test_paged_reads_through_permuted_tables(rng):
     for seed in (0, 1):
         r2 = np.random.default_rng(seed)
         bt = r2.permutation(n)[:b * nb].reshape(b, nb).astype(np.int32)
-        kp = np.zeros((n, bs, kv, d), np.float32)
-        vp = np.zeros((n, bs, kv, d), np.float32)
+        kp = np.zeros((kv, n, bs, d), np.float32)
+        vp = np.zeros((kv, n, bs, d), np.float32)
         for i in range(b):
             for j in range(nb):
-                kp[bt[i, j]] = seq[i, j * bs:(j + 1) * bs]
-                vp[bt[i, j]] = val[i, j * bs:(j + 1) * bs]
+                kp[:, bt[i, j]] = seq[i, j * bs:(j + 1) * bs].swapaxes(0, 1)
+                vp[:, bt[i, j]] = val[i, j * bs:(j + 1) * bs].swapaxes(0, 1)
         outs.append(np.asarray(paged_decode_attention_xla(
             q, kp, vp, jnp.asarray(bt), jnp.asarray(kv_len))))
     np.testing.assert_allclose(outs[0], outs[1], atol=2e-5, rtol=2e-5)
