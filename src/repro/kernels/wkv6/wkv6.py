@@ -2,9 +2,10 @@
 
 Grid (batch, head, time_chunks) with the chunk axis innermost; the
 recurrent state lives in VMEM scratch across the chunk sweep (transposed,
-[Dv, D]), starting from the initial state ``s0``.  Within a chunk the intra-chunk attention uses the
-pairwise decay exp(cumlogw[t-1] - cumlogw[s]) whose exponents are all <= 0,
-so the kernel is stable for arbitrarily strong data-dependent decay (the
+[Dv, D]), starting from the initial state ``s0``.  Within a chunk the
+intra-chunk attention uses the pairwise decay
+exp(cumlogw[t-1] - cumlogw[s]) whose exponents are all <= 0, so the kernel
+is stable for arbitrarily strong data-dependent decay (the
 factored r*exp(cw) / k*exp(-cw) form would overflow); it is built one key
 position at a time, C*D floats live per step, plus the state tile.
 

@@ -353,9 +353,10 @@ def attn_paged_decode(cfg: ModelConfig, spec: LayerSpec, p, x, pool,
 
     x: [B, 1, d]; pool: {"k": [KV, N, bs, hd], "v": [KV, N, bs, dv]} — one
     layer's head-major physical block pool; block_tables: [B, nb] int32
-    (rows padded with a valid null block); kv_len: [B] current lengths.  The new token's
-    K/V is scattered into slot ``kv_len`` of its sequence's block table, then
-    attention reads the cache through the table (kernels.paged_attention).
+    (rows padded with a valid null block); kv_len: [B] current lengths.  The
+    new token's K/V is scattered into slot ``kv_len`` of its sequence's block
+    table, then attention reads the cache through the table
+    (kernels.paged_attention).
     Returns (y, updated pool).  MLA and sliding-window layers keep their
     latent/ring cache paths — the serving runtime gates on api.paged_compatible.
     Sharded decode (head-TP / sequence-sharded pools) is not implemented:

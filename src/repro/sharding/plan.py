@@ -15,7 +15,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 
-
 @dataclass(frozen=True)
 class ShardingPlan:
     batch_axes: tuple[str, ...] = ()        # activation batch dims
