@@ -26,6 +26,7 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.paged_attention import (paged_decode_attention,
                                            paged_window_attention)
 from repro.models.common import apply_dense, apply_mrope, apply_rope, dense_init
+from repro.obs.trace import device_scope
 from repro.sharding.plan import ShardingPlan, axis_size, constrain, divisible
 
 # --------------------------------------------------------------------- init
@@ -377,8 +378,9 @@ def attn_paged_decode(cfg: ModelConfig, spec: LayerSpec, p, x, pool,
     bs = pool["k"].shape[2]
     blk = block_tables[jnp.arange(b), kv_len // bs]          # [B] physical ids
     off = kv_len % bs
-    k_pool = pool["k"].at[:, blk, off].set(jnp.swapaxes(k[:, 0], 0, 1))
-    v_pool = pool["v"].at[:, blk, off].set(jnp.swapaxes(v[:, 0], 0, 1))
+    with device_scope("kv_write"):
+        k_pool = pool["k"].at[:, blk, off].set(jnp.swapaxes(k[:, 0], 0, 1))
+        v_pool = pool["v"].at[:, blk, off].set(jnp.swapaxes(v[:, 0], 0, 1))
     out = paged_decode_attention(q[:, 0], k_pool, v_pool, block_tables,
                                  kv_len + 1, softcap=cfg.attn_softcap)
     y = apply_dense(p["o"], out.reshape(b, -1))
@@ -412,8 +414,9 @@ def attn_paged_spec(cfg: ModelConfig, spec: LayerSpec, p, x, pool,
     if cfg.rope == "mrope":
         positions = jnp.broadcast_to(positions, (3, b, t))
     q, k, v = _qkv(cfg, p, x, positions)
-    k_pool = pool["k"].at[:, blk, off].set(jnp.moveaxis(k, 2, 0))
-    v_pool = pool["v"].at[:, blk, off].set(jnp.moveaxis(v, 2, 0))
+    with device_scope("kv_write"):
+        k_pool = pool["k"].at[:, blk, off].set(jnp.moveaxis(k, 2, 0))
+        v_pool = pool["v"].at[:, blk, off].set(jnp.moveaxis(v, 2, 0))
     out = paged_window_attention(q, k_pool, v_pool, block_tables, kv_len,
                                  softcap=cfg.attn_softcap)
     y = apply_dense(p["o"], out.reshape(b, t, -1))

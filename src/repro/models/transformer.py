@@ -28,6 +28,7 @@ from repro.models.common import apply_norm, norm_init, softcap
 from repro.models.mlp import (channel_mix_apply, channel_mix_init, mlp_apply,
                               mlp_init, token_shift)
 from repro.models.moe import moe_apply, moe_init
+from repro.obs.trace import device_scope
 from repro.sharding.plan import ShardingPlan, batch_spec, constrain, resid_spec
 
 
@@ -85,25 +86,26 @@ def block_apply(cfg: ModelConfig, spec: LayerSpec, p, x, *, positions, plan,
         raise NotImplementedError(
             f"paged decode only supports attention mixers, got {spec.mixer}")
     if spec.mixer == "attn":
-        if mode == "decode" and block_tables is not None \
-                and spec_scatter is not None:
-            mx, c = attn.attn_paged_spec(cfg, spec, p["mixer"], h,
-                                         cache["mixer"], block_tables,
-                                         kv_len, *spec_scatter, plan=plan)
-        elif mode == "decode" and block_tables is not None:
-            mx, c = attn.attn_paged_decode(cfg, spec, p["mixer"], h,
-                                           cache["mixer"], block_tables,
-                                           kv_len, plan=plan)
-        elif mode == "decode":
-            mx, c = attn.attn_decode(cfg, spec, p["mixer"], h, cache["mixer"],
-                                     kv_len, plan=plan)
-        else:
-            # a cache entry in prefill mode is a cached *prefix* K/V to
-            # continue from (serving.prefix_cache suffix prefill)
-            mx, c = attn.attn_prefill(cfg, spec, p["mixer"], h,
-                                      positions=positions, plan=plan,
-                                      cache_len=cache_len, kv_len=kv_len,
-                                      prefix=(cache or {}).get("mixer"))
+        with device_scope("attention"):
+            if mode == "decode" and block_tables is not None \
+                    and spec_scatter is not None:
+                mx, c = attn.attn_paged_spec(cfg, spec, p["mixer"], h,
+                                             cache["mixer"], block_tables,
+                                             kv_len, *spec_scatter, plan=plan)
+            elif mode == "decode" and block_tables is not None:
+                mx, c = attn.attn_paged_decode(cfg, spec, p["mixer"], h,
+                                               cache["mixer"], block_tables,
+                                               kv_len, plan=plan)
+            elif mode == "decode":
+                mx, c = attn.attn_decode(cfg, spec, p["mixer"], h,
+                                         cache["mixer"], kv_len, plan=plan)
+            else:
+                # a cache entry in prefill mode is a cached *prefix* K/V to
+                # continue from (serving.prefix_cache suffix prefill)
+                mx, c = attn.attn_prefill(cfg, spec, p["mixer"], h,
+                                          positions=positions, plan=plan,
+                                          cache_len=cache_len, kv_len=kv_len,
+                                          prefix=(cache or {}).get("mixer"))
     elif spec.mixer == "mamba":
         if mode != "decode" and cache is not None:
             raise NotImplementedError(
@@ -129,27 +131,28 @@ def block_apply(cfg: ModelConfig, spec: LayerSpec, p, x, *, positions, plan,
     x = x + mx
     x = constrain(x, resid_spec(plan, x), plan)
 
-    h2 = apply_norm(cfg, p["norm2"], x)
-    if spec.mlp == "moe":
-        my, moe_aux = moe_apply(cfg, p["mlp"], h2, plan=plan)
-        aux.update(moe_aux)
-    elif spec.mixer == "rwkv6":
-        if mode == "decode":
-            shifted = cache["cm_shift"][:, None]
-            my = channel_mix_apply(cfg, p["mlp"], h2, shifted)
-            new_cache["cm_shift"] = h2[:, 0]
+    with device_scope("mlp"):
+        h2 = apply_norm(cfg, p["norm2"], x)
+        if spec.mlp == "moe":
+            my, moe_aux = moe_apply(cfg, p["mlp"], h2, plan=plan)
+            aux.update(moe_aux)
+        elif spec.mixer == "rwkv6":
+            if mode == "decode":
+                shifted = cache["cm_shift"][:, None]
+                my = channel_mix_apply(cfg, p["mlp"], h2, shifted)
+                new_cache["cm_shift"] = h2[:, 0]
+            else:
+                my = channel_mix_apply(cfg, p["mlp"], h2, token_shift(h2))
+                if cache_len:
+                    if kv_len is not None:
+                        new_cache["cm_shift"] = jax.vmap(
+                            lambda v, i: v[jnp.maximum(i - 1, 0)])(h2, kv_len)
+                    else:
+                        new_cache["cm_shift"] = h2[:, -1]
         else:
-            my = channel_mix_apply(cfg, p["mlp"], h2, token_shift(h2))
-            if cache_len:
-                if kv_len is not None:
-                    new_cache["cm_shift"] = jax.vmap(
-                        lambda v, i: v[jnp.maximum(i - 1, 0)])(h2, kv_len)
-                else:
-                    new_cache["cm_shift"] = h2[:, -1]
-    else:
-        my = mlp_apply(cfg, p["mlp"], h2)
-    if cfg.post_block_norms:
-        my = apply_norm(cfg, p["norm2_post"], my)
+            my = mlp_apply(cfg, p["mlp"], h2)
+        if cfg.post_block_norms:
+            my = apply_norm(cfg, p["norm2_post"], my)
     x = x + my
     x = constrain(x, resid_spec(plan, x), plan)
     return x, (new_cache if new_cache else None), aux
@@ -373,8 +376,10 @@ def lm_paged_decode_step(cfg: ModelConfig, params, tokens, pools,
     x, new_pools, _ = apply_stack(cfg, params, x, positions=None, plan=plan,
                                   mode="decode", cache=pools, kv_len=kv_len,
                                   block_tables=block_tables)
-    x = apply_norm(cfg, params["final_norm"], x)
-    return lm_head(cfg, params, x[:, 0]), new_pools
+    with device_scope("head"):
+        x = apply_norm(cfg, params["final_norm"], x)
+        logits = lm_head(cfg, params, x[:, 0])
+    return logits, new_pools
 
 
 def lm_paged_spec_step(cfg: ModelConfig, params, tokens, pools, block_tables,
@@ -390,5 +395,7 @@ def lm_paged_spec_step(cfg: ModelConfig, params, tokens, pools, block_tables,
                                   mode="decode", cache=pools, kv_len=kv_len,
                                   block_tables=block_tables,
                                   spec_scatter=(blk, off))
-    x = apply_norm(cfg, params["final_norm"], x)
-    return lm_head(cfg, params, x), new_pools
+    with device_scope("head"):
+        x = apply_norm(cfg, params["final_norm"], x)
+        logits = lm_head(cfg, params, x)
+    return logits, new_pools

@@ -40,9 +40,44 @@ axis ``Request.finish_time`` already uses, so spans and SLO accounting
 agree.  A disabled tracer (``Tracer(enabled=False)`` / ``NULL_TRACER``) is
 a no-op on every call; engines hold one unconditionally and hot paths guard
 argument construction behind ``tracer.enabled``.
+
+The engine's host phases go into the profiler's own trace, next to the
+device's operations, as ``jax.profiler.TraceAnnotation`` scopes named
+``uellm/<phase>`` (``phase``); they run whether or not a ``Tracer`` is
+enabled and cost ~1 us a scope when no profiler is running:
+
+    host phase       | covers (PagedEngine.run_continuous)
+    -----------------+---------------------------------------------------
+    iteration        | one pass of the engine loop
+    admit            | ``_admit`` (unchunked prefill nests inside it)
+    prefill          | one ``_run_chunk``: model, scatter, first token
+    finish           | ``_finish``
+    grow             | block growth for the step (preemption included)
+    gauges           | the KV utilisation gauges
+    draft            | the drafter's proposals (speculative path)
+    view             | masked block tables, lengths, tokens, and uploads
+    dispatch         | the ``_decode`` / ``_verify`` call
+    sample           | the greedy pick's dispatch
+    sync             | the blocking read of the step's tokens
+    emit             | token appends, inter-token stamps, tracer spans
+    drain            | the final ``block_until_ready`` and leak audit
+
+The decode step's parts carry ``jax.named_scope`` names
+(``device_scope``), so the device's operations say which part they
+belong to:
+
+    device scope     | covers
+    -----------------+---------------------------------------------------
+    attention        | q/k/v projection, the paged kernel, o projection
+    kv_write         | the new token's K/V scattered into the pool
+    mlp              | the feed-forward block
+    head             | final norm and the LM head
+    pick             | the greedy pick (named only where it is traced
+                     | inside a jitted step; eager ops carry no scope)
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -76,6 +111,34 @@ INSTANT_NAMES = frozenset({
     "replica_failed", "retry", "brownout",
 })
 EVENT_NAMES = SPAN_NAMES | INSTANT_NAMES
+HOST_PHASES = frozenset({
+    "iteration", "admit", "prefill", "finish", "grow", "gauges", "draft",
+    "view", "dispatch", "sample", "sync", "emit", "drain",
+})
+DEVICE_SCOPES = frozenset({"attention", "kv_write", "mlp", "head", "pick"})
+PHASE_PREFIX = "uellm/"
+
+
+def phase(name: str):
+    """Profiler scope ``uellm/<name>`` around one of the engine's
+    ``HOST_PHASES``; inert when no profiler is running."""
+    assert name in HOST_PHASES, f"{name!r} is not a host phase"
+    return _trace_annotation()(PHASE_PREFIX + name)
+
+
+def device_scope(name: str):
+    """``jax.named_scope`` for one of the decode step's ``DEVICE_SCOPES``:
+    names the operations traced inside it."""
+    assert name in DEVICE_SCOPES, f"{name!r} is not a device scope"
+    import jax
+    return jax.named_scope(name)
+
+
+@functools.cache
+def _trace_annotation():
+    # imported on first use: the simulator imports obs without jax
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation
 
 
 @dataclass

@@ -52,7 +52,7 @@ from repro.core.types import Request
 from repro.models import api
 from repro.serving.engine import BatchResult
 from repro.obs.trace import (NULL_TRACER, ROW_QUEUE, LatencyBreakdown,
-                             Tracer, slot_row)
+                             Tracer, phase, slot_row)
 from repro.serving.kv_cache import BlockAllocator
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.sampling import greedy
@@ -127,7 +127,6 @@ class PagedBatchResult(BatchResult):
     #   tokens for several sequences at once)
     waste_vs_padded: float = 0.0   # mean 1 - allocated / max-len reservation
     peak_residents: int = 0        # high-water mark of concurrent sequences
-    hol_skips: int = 0             # admissions that jumped a blocked queue head
     # --- prefix-cache accounting (zeros with prefix_cache=False) ---
     prefix_lookups: int = 0
     prefix_hits: int = 0
@@ -496,85 +495,87 @@ class PagedEngine:
         head may instead evict resident(s) with more SLO slack than its own.
         Unchunked, each admitted prompt is prefilled to completion here;
         chunked, prefill begins and the main loop interleaves the chunks."""
-        admitted = 0
-        t0 = time.perf_counter()
-        while queue:
-            free = [s for s in range(self.pcfg.max_batch)
-                    if st.active[s] is None]
-            if not free:
-                break
-            pick = None
-            for qi in range(min(len(queue), self.pcfg.admit_lookahead + 1)):
-                if self.can_admit(st, queue[qi], budget, outs):
-                    pick = qi
+        with phase("admit"):
+            admitted = 0
+            t0 = time.perf_counter()
+            while queue:
+                free = [s for s in range(self.pcfg.max_batch)
+                        if st.active[s] is None]
+                if not free:
                     break
-            if pick is None and self.pcfg.preempt:
-                head = queue[0]
-                now = time.perf_counter() - self._serve_t0
-                slack_h = self._slack(head, now)
-                eligible = sorted(
-                    (s for s in st.decoding_slots()
-                     if self._slack(st.active[s], now) > slack_h),
-                    key=lambda s: self._slack(st.active[s], now),
-                    reverse=True)
-                # feasibility precheck: evict only the slack-descending
-                # victim prefix that actually buys the head admission —
-                # never throw away residents' generated work for zero gain
-                full, cached = self._prefix_discount(st, head)
-                worst = self._worst_blocks(head, budget,
-                                           self._gen_count(outs, head))
-                avail = st.alloc.available
-                reserved = self._reserved_remaining(st, budget, outs)
-                n_evict = 0
-                for k, s in enumerate(eligible, start=1):
-                    a_gain, r_gain = self._preempt_gain(st, s, budget, outs)
-                    avail += a_gain
-                    reserved -= r_gain
-                    if avail - cached >= max(0, worst - full) + reserved:
-                        n_evict = k
+                pick = None
+                for qi in range(min(len(queue),
+                                    self.pcfg.admit_lookahead + 1)):
+                    if self.can_admit(st, queue[qi], budget, outs):
+                        pick = qi
                         break
-                for s in eligible[:n_evict]:
-                    self._preempt(st, s, outs, res, queue)
-                if n_evict and self.can_admit(st, head, budget, outs):
-                    pick = 0
-            if pick is None:
+                if pick is None and self.pcfg.preempt:
+                    head = queue[0]
+                    now = time.perf_counter() - self._serve_t0
+                    slack_h = self._slack(head, now)
+                    eligible = sorted(
+                        (s for s in st.decoding_slots()
+                         if self._slack(st.active[s], now) > slack_h),
+                        key=lambda s: self._slack(st.active[s], now),
+                        reverse=True)
+                    # feasibility precheck: evict only the slack-descending
+                    # victim prefix that actually buys the head admission
+                    # — never throw away residents' generated work for zero
+                    # gain
+                    full, cached = self._prefix_discount(st, head)
+                    worst = self._worst_blocks(head, budget,
+                                               self._gen_count(outs, head))
+                    avail = st.alloc.available
+                    reserved = self._reserved_remaining(st, budget, outs)
+                    n_evict = 0
+                    for k, s in enumerate(eligible, start=1):
+                        a_gain, r_gain = self._preempt_gain(st, s, budget,
+                                                            outs)
+                        avail += a_gain
+                        reserved -= r_gain
+                        if avail - cached >= max(0, worst - full) + reserved:
+                            n_evict = k
+                            break
+                    for s in eligible[:n_evict]:
+                        self._preempt(st, s, outs, res, queue)
+                    if n_evict and self.can_admit(st, head, budget, outs):
+                        pick = 0
+                if pick is None:
+                    if self.tracer.enabled:
+                        self.tracer.instant(
+                            "admission_reject",
+                            time.perf_counter() - self._serve_t0,
+                            track=self.track,
+                            args={"rid": queue[0].rid, "queued": len(queue)})
+                    break
+                r = queue.pop(pick)
+                slot = min(s for s in range(self.pcfg.max_batch)
+                           if st.active[s] is None)
+                st.active[slot] = r
+                now = time.perf_counter() - self._serve_t0
+                if r.start_time is None:
+                    r.start_time = max(r.arrival, now)
+                bd = self._bd.setdefault(r.rid, LatencyBreakdown())
+                qt0 = self._qstart.pop(r.rid, r.arrival)
+                bd.queue_wait_s += max(0.0, now - qt0)
                 if self.tracer.enabled:
-                    self.tracer.instant(
-                        "admission_reject",
-                        time.perf_counter() - self._serve_t0,
-                        track=self.track,
-                        args={"rid": queue[0].rid, "queued": len(queue)})
-                break
-            if pick:
-                res.hol_skips += 1
-            r = queue.pop(pick)
-            slot = min(s for s in range(self.pcfg.max_batch)
-                       if st.active[s] is None)
-            st.active[slot] = r
-            now = time.perf_counter() - self._serve_t0
-            if r.start_time is None:
-                r.start_time = max(r.arrival, now)
-            bd = self._bd.setdefault(r.rid, LatencyBreakdown())
-            qt0 = self._qstart.pop(r.rid, r.arrival)
-            bd.queue_wait_s += max(0.0, now - qt0)
-            if self.tracer.enabled:
-                self.tracer.span("queued", min(qt0, now), now,
-                                 track=self.track, row=ROW_QUEUE,
-                                 args={"rid": r.rid})
-                self.tracer.instant("admitted", now, track=self.track,
-                                    row=slot_row(slot),
-                                    args={"rid": r.rid, "hol_skip": pick})
-            self._begin_prefill(st, slot, r, outs, res)
-            if not self._chunk:
-                while slot in st.prefilling:
-                    self._run_chunk(st, slot, outs, res)
-            admitted += 1
-            res.peak_residents = max(
-                res.peak_residents, sum(a is not None for a in st.active))
-        if admitted:
-            res.admission_waves += 1
-            res.prefill_s += time.perf_counter() - t0
-        return admitted
+                    self.tracer.span("queued", min(qt0, now), now,
+                                     track=self.track, row=ROW_QUEUE,
+                                     args={"rid": r.rid})
+                    self.tracer.instant("admitted", now, track=self.track,
+                                        row=slot_row(slot),
+                                        args={"rid": r.rid, "hol_skip": pick})
+                self._begin_prefill(st, slot, r, outs, res)
+                if not self._chunk:
+                    while slot in st.prefilling:
+                        self._run_chunk(st, slot, outs, res)
+                admitted += 1
+                res.peak_residents = max(
+                    res.peak_residents, sum(a is not None for a in st.active))
+            if admitted:
+                res.admission_waves += 1
+                res.prefill_s += time.perf_counter() - t0
+            return admitted
 
     def _padded_len(self, n: int) -> int:
         bs = self.pcfg.block_size
@@ -645,63 +646,64 @@ class PagedEngine:
         unchunked).  Returns True when the prompt completes — kv_len is set,
         the prompt chain published, and the first output token emitted
         (or the preempted resume token restored)."""
-        pg: PrefillProgress = st.prefilling[slot]
-        r = st.active[slot]
-        prompt, ln = pg.prompt, len(pg.prompt)
-        bs = self.pcfg.block_size
-        table = st.alloc.tables[slot]
-        remaining = ln - pg.done
-        sn = remaining if not self._chunk else min(remaining, self._chunk)
-        start = pg.done
-        tc0 = time.perf_counter()
-        cl = self._padded_len(sn)
-        toks = np.zeros((1, cl), np.int32)
-        toks[0, :sn] = prompt[pg.done:pg.done + sn]
-        if pg.done:
-            n_blk = -(-pg.done // bs)
-            pref = self._gather_prefix(st.pools, table[:n_blk], pg.done)
-            logits, cache = self._prefill_suffix(
-                self.params, jnp.asarray(toks),
-                jnp.asarray([sn], jnp.int32), cl, pref)
-        else:
-            logits, cache = self._prefill(self.params, jnp.asarray(toks),
-                                          jnp.asarray([sn], jnp.int32), cl)
-        pos = pg.done + np.arange(cl)
-        blk = np.asarray([table[p // bs] if p < ln
-                          else st.null_block for p in pos], np.int32)
-        off = (pos % bs).astype(np.int32)
-        st.pools = self._scatter(st.pools, cache, jnp.asarray(blk),
-                                 jnp.asarray(off))
-        pg.done += sn
-        res.prefill_tokens += cl
-        res.prefill_chunks += 1
-        if pg.done < ln:
+        with phase("prefill"):
+            pg: PrefillProgress = st.prefilling[slot]
+            r = st.active[slot]
+            prompt, ln = pg.prompt, len(pg.prompt)
+            bs = self.pcfg.block_size
+            table = st.alloc.tables[slot]
+            remaining = ln - pg.done
+            sn = remaining if not self._chunk else min(remaining, self._chunk)
+            start = pg.done
+            tc0 = time.perf_counter()
+            cl = self._padded_len(sn)
+            toks = np.zeros((1, cl), np.int32)
+            toks[0, :sn] = prompt[pg.done:pg.done + sn]
+            if pg.done:
+                n_blk = -(-pg.done // bs)
+                pref = self._gather_prefix(st.pools, table[:n_blk], pg.done)
+                logits, cache = self._prefill_suffix(
+                    self.params, jnp.asarray(toks),
+                    jnp.asarray([sn], jnp.int32), cl, pref)
+            else:
+                logits, cache = self._prefill(self.params, jnp.asarray(toks),
+                                              jnp.asarray([sn], jnp.int32), cl)
+            pos = pg.done + np.arange(cl)
+            blk = np.asarray([table[p // bs] if p < ln
+                              else st.null_block for p in pos], np.int32)
+            off = (pos % bs).astype(np.int32)
+            st.pools = self._scatter(st.pools, cache, jnp.asarray(blk),
+                                     jnp.asarray(off))
+            pg.done += sn
+            res.prefill_tokens += cl
+            res.prefill_chunks += 1
+            if pg.done < ln:
+                self._chunk_telemetry(r, pg, slot, start, sn, tc0)
+                return False
+            del st.prefilling[slot]
+            st.kv_len[slot] = ln
+            if st.prefix is not None:
+                # publish the prompt's full blocks so same-prefix requests
+                # admitted while this one decodes already hit them
+                st.prefix.insert(prompt, table, (ln // bs) * bs)
+            if pg.resume_tok is not None:
+                st.cur_tok[slot] = pg.resume_tok
+            else:
+                first = int(np.asarray(greedy(logits, self.cfg.vocab_size))[0])
+                st.cur_tok[slot] = first
+                outs[r.rid] = [first]
+                r.first_token_time = max(
+                    r.arrival, time.perf_counter() - self._serve_t0)
+                bd = self._bd.get(r.rid)
+                if bd is not None:
+                    bd.ttft_s = max(0.0, r.first_token_time - r.arrival)
+            # reset the slot's inter-token stamp: None marks a fresh sequence,
+            # so neither a previous occupant's stale stamp nor the wave-start
+            # first-token gap (TTFT, with its one-time sync costs) pollutes the
+            # decode-gap series — gaps count between consecutive decode steps
+            self._last_emit[slot] = None
             self._chunk_telemetry(r, pg, slot, start, sn, tc0)
-            return False
-        del st.prefilling[slot]
-        st.kv_len[slot] = ln
-        if st.prefix is not None:
-            # publish the prompt's full blocks so same-prefix requests
-            # admitted while this one decodes already hit them
-            st.prefix.insert(prompt, table, (ln // bs) * bs)
-        if pg.resume_tok is not None:
-            st.cur_tok[slot] = pg.resume_tok
-        else:
-            first = int(np.asarray(greedy(logits, self.cfg.vocab_size))[0])
-            st.cur_tok[slot] = first
-            outs[r.rid] = [first]
-            r.first_token_time = max(
-                r.arrival, time.perf_counter() - self._serve_t0)
-            bd = self._bd.get(r.rid)
-            if bd is not None:
-                bd.ttft_s = max(0.0, r.first_token_time - r.arrival)
-        # reset the slot's inter-token stamp: None marks a fresh sequence,
-        # so neither a previous occupant's stale stamp nor the wave-start
-        # first-token gap (TTFT, with its one-time sync costs) pollutes the
-        # decode-gap series — gaps count between consecutive decode steps
-        self._last_emit[slot] = None
-        self._chunk_telemetry(r, pg, slot, start, sn, tc0)
-        return True
+            return True
 
     def _chunk_telemetry(self, r: Request, pg: PrefillProgress, slot: int,
                          start: int, sn: int, tc0: float) -> None:
@@ -744,64 +746,70 @@ class PagedEngine:
         b = self.pcfg.max_batch
         t_w = self.pcfg.spec_tokens + 1
         ts0 = time.perf_counter()
-        bt, kv, ct = st.masked_decode_view()
-        win_eff = np.zeros(b, np.int32)
-        for slot in decoding:
-            win_eff[slot] = win[slot]
-        toks = np.zeros((b, t_w), np.int32)
-        toks[:, 0] = ct
-        toks[:, 1:] = drafts
-        # host-side scatter targets: window position t of slot s lands at
-        # logical position kv+t -> (table[(kv+t)//bs], (kv+t)%bs); invalid
-        # positions (masked slot, past the slot's window) go to the null
-        # block so the batched write never touches live blocks
-        pos = kv[:, None] + np.arange(t_w)[None, :]
-        valid = np.arange(t_w)[None, :] < win_eff[:, None]
-        blk_idx = np.minimum(pos // bs, bt.shape[1] - 1)
-        blk = np.take_along_axis(bt, blk_idx, axis=1)
-        blk = np.where(valid, blk, st.null_block).astype(np.int32)
-        off = np.where(valid, pos % bs, 0).astype(np.int32)
-        logits, st.pools = self._verify(
-            self.params, jnp.asarray(toks), st.pools, jnp.asarray(bt),
-            jnp.asarray(kv), jnp.asarray(blk), jnp.asarray(off))
-        g = np.asarray(greedy(logits.reshape(b * t_w, -1),
-                              self.cfg.vocab_size)).reshape(b, t_w)
-        now = time.perf_counter()
-        for slot in decoding:
-            r = st.active[slot]
-            k_eff = int(win[slot]) - 1
-            j = 0
-            while j < k_eff and int(drafts[slot, j]) == int(g[slot, j]):
-                j += 1
-            n_emit = j + 1           # accepted drafts + the bonus token
-            emitted = [int(x) for x in g[slot, :n_emit]]
-            outs[r.rid].extend(emitted)
-            st.cur_tok[slot] = emitted[-1]
-            st.kv_len[slot] += n_emit
-            res.drafted_tokens += k_eff
-            res.accepted_tokens += j
-            if self.cost_profiler is not None and k_eff > 0:
-                # measured acceptance: the live signal that retires the
-                # static planning prior in launch/serve.py
-                self.cost_profiler.observe_acceptance(j, k_eff)
-            res.spec_rolled_blocks += st.truncate_blocks(
-                slot, int(st.kv_len[slot]), bs)
-            prev = self._last_emit.get(slot)
-            if prev is not None:
-                gap = (now - prev) / n_emit
-                res.inter_token_s.extend([gap] * n_emit)
-            self._last_emit[slot] = now
-            if self.tracer.enabled:
-                # a window of 1 (no drafts proposed) is a plain decode
-                # iteration routed through the verify kernel — name it so
-                self.tracer.span(
-                    "verify" if k_eff > 0 else "decode",
-                    ts0 - self._serve_t0, now - self._serve_t0,
-                    track=self.track, row=slot_row(slot),
-                    args={"rid": r.rid, "drafted": k_eff, "accepted": j,
-                          "emitted": n_emit, "batch": len(decoding),
-                          "kv": float(np.mean(kv[decoding])),
-                          "q_tokens": t_w})
+        with phase("view"):
+            bt, kv, ct = st.masked_decode_view()
+            win_eff = np.zeros(b, np.int32)
+            for slot in decoding:
+                win_eff[slot] = win[slot]
+            toks = np.zeros((b, t_w), np.int32)
+            toks[:, 0] = ct
+            toks[:, 1:] = drafts
+            # host-side scatter targets: window position t of slot s lands
+            # at logical position kv+t -> (table[(kv+t)//bs], (kv+t)%bs);
+            # invalid positions (masked slot, past the slot's window) go to
+            # the null block so the batched write never touches live blocks
+            pos = kv[:, None] + np.arange(t_w)[None, :]
+            valid = np.arange(t_w)[None, :] < win_eff[:, None]
+            blk_idx = np.minimum(pos // bs, bt.shape[1] - 1)
+            blk = np.take_along_axis(bt, blk_idx, axis=1)
+            blk = np.where(valid, blk, st.null_block).astype(np.int32)
+            off = np.where(valid, pos % bs, 0).astype(np.int32)
+            toks_d, bt_d, kv_d, blk_d, off_d = (
+                jnp.asarray(x) for x in (toks, bt, kv, blk, off))
+        with phase("dispatch"):
+            logits, st.pools = self._verify(
+                self.params, toks_d, st.pools, bt_d, kv_d, blk_d, off_d)
+        with phase("sample"):
+            picked = greedy(logits.reshape(b * t_w, -1), self.cfg.vocab_size)
+        with phase("sync"):
+            g = np.asarray(picked).reshape(b, t_w)
+        with phase("emit"):
+            now = time.perf_counter()
+            for slot in decoding:
+                r = st.active[slot]
+                k_eff = int(win[slot]) - 1
+                j = 0
+                while j < k_eff and int(drafts[slot, j]) == int(g[slot, j]):
+                    j += 1
+                n_emit = j + 1           # accepted drafts + the bonus token
+                emitted = [int(x) for x in g[slot, :n_emit]]
+                outs[r.rid].extend(emitted)
+                st.cur_tok[slot] = emitted[-1]
+                st.kv_len[slot] += n_emit
+                res.drafted_tokens += k_eff
+                res.accepted_tokens += j
+                if self.cost_profiler is not None and k_eff > 0:
+                    # measured acceptance: the live signal that retires the
+                    # static planning prior in launch/serve.py
+                    self.cost_profiler.observe_acceptance(j, k_eff)
+                res.spec_rolled_blocks += st.truncate_blocks(
+                    slot, int(st.kv_len[slot]), bs)
+                prev = self._last_emit.get(slot)
+                if prev is not None:
+                    gap = (now - prev) / n_emit
+                    res.inter_token_s.extend([gap] * n_emit)
+                self._last_emit[slot] = now
+                if self.tracer.enabled:
+                    # a window of 1 (no drafts proposed) is a plain decode
+                    # iteration routed through the verify kernel — name it so
+                    self.tracer.span(
+                        "verify" if k_eff > 0 else "decode",
+                        ts0 - self._serve_t0, now - self._serve_t0,
+                        track=self.track, row=slot_row(slot),
+                        args={"rid": r.rid, "drafted": k_eff, "accepted": j,
+                              "emitted": n_emit, "batch": len(decoding),
+                              "kv": float(np.mean(kv[decoding])),
+                              "q_tokens": t_w})
 
     # ------------------------------------------------------------- abort path
     def _abort(self, st: PagedDecodeState, slot: int, r: Request,
@@ -897,177 +905,202 @@ class PagedEngine:
             self._admit(st, queue, outs, res, budget)
         steps = 0
         while True:
-            if abort_at:
-                # injected aborts fire before finishes: an abort threshold
-                # already reached must not race the stop count into _finish
-                self._sweep_aborts(st, queue, outs, res, abort_at)
-                if queue and any(a is None for a in st.active):
+            with phase("iteration"):
+                if abort_at:
+                    # injected aborts fire before finishes: an abort
+                    # threshold already reached must not race the stop
+                    # count into _finish
+                    self._sweep_aborts(st, queue, outs, res, abort_at)
+                    if queue and any(a is None for a in st.active):
+                        self._admit(st, queue, outs, res, budget)
+                # a) finish/admit fixpoint: retiring slots frees blocks which
+                #    can admit new prompts, whose stop count may already be
+                #    met by their prefill token (stop==1) — loop until stable
+                #    so the decode step below never runs a completed sequence
+                progress = True
+                while progress:
+                    progress = False
+                    for slot, r in enumerate(st.active):
+                        if r is not None and slot not in st.prefilling \
+                                and len(outs[r.rid]) >= min(
+                                    r.true_output_len, budget):
+                            with phase("finish"):
+                                self._finish(st, slot, r, outs)
+                            progress = True
+                    if progress and queue:
+                        self._admit(st, queue, outs, res, budget)
+                # iteration-level admission: with chunking or preemption the
+                # queue is reconsidered every iteration, not only on finishes
+                # — chunked admissions just open a cursor (cheap), and
+                # preemption must see tight arrivals while slack residents
+                # still decode
+                if queue and (self._chunk or self.pcfg.preempt) \
+                        and any(a is None for a in st.active):
                     self._admit(st, queue, outs, res, budget)
-            # a) finish/admit fixpoint: retiring slots frees blocks which can
-            #    admit new prompts, whose stop count may already be met by
-            #    their prefill token (stop==1) — loop until stable so the
-            #    decode step below never runs a completed sequence
-            progress = True
-            while progress:
-                progress = False
-                for slot, r in enumerate(st.active):
-                    if r is not None and slot not in st.prefilling \
-                            and len(outs[r.rid]) >= min(
-                                r.true_output_len, budget):
-                        self._finish(st, slot, r, outs)
-                        progress = True
-                if progress and queue:
-                    self._admit(st, queue, outs, res, budget)
-            # iteration-level admission: with chunking or preemption the
-            # queue is reconsidered every iteration, not only on finishes —
-            # chunked admissions just open a cursor (cheap), and preemption
-            # must see tight arrivals while slack residents still decode
-            if queue and (self._chunk or self.pcfg.preempt) \
-                    and any(a is None for a in st.active):
-                self._admit(st, queue, outs, res, budget)
-            if not any(a is not None for a in st.active):
-                break
-            # b) one prefill chunk (chunked mode; unchunked prompts complete
-            #    inside _admit).  Multiple mid-prefill slots take turns, so
-            #    per-iteration prefill work stays <= one chunk
-            if st.prefilling:
-                pre_slots = sorted(st.prefilling)
-                slot = pre_slots[rr % len(pre_slots)]
-                rr += 1
-                had_decoders = bool(st.decoding_slots())
-                t0 = time.perf_counter()
-                self._run_chunk(st, slot, outs, res)
-                dt = time.perf_counter() - t0
-                res.prefill_s += dt
-                if had_decoders:
-                    res.prefill_stall_s += dt
-                    self._stalls.append(dt)
-            decoding = st.decoding_slots()
-            # just-admitted (or just-completed-prefill) sequences may already
-            # be at their stop count — let the fixpoint retire them before
-            # they join a decode step
-            decoding = [s for s in decoding
-                        if len(outs[st.active[s].rid]) < min(
-                            st.active[s].true_output_len, budget)]
-            if not decoding:
-                continue
-            # c) speculative draft window: propose *before* block growth so
-            #    the grower knows the full write horizon.  Per-slot draft
-            #    width is capped by the tokens the request may still emit
-            #    and by its block-table width, so a near-finished or
-            #    near-max_seq sequence never drafts past its own end
-            k_spec = self.pcfg.spec_tokens
-            win = np.ones(self.pcfg.max_batch, np.int32)
-            drafts: Optional[np.ndarray] = None
-            if k_spec > 0:
-                drafts = np.zeros((self.pcfg.max_batch, k_spec), np.int32)
-                win = np.zeros(self.pcfg.max_batch, np.int32)
-                for slot in decoding:
-                    r = st.active[slot]
-                    m = min(r.true_output_len, budget) - len(outs[r.rid])
-                    cap = min(k_spec, m - 1,
-                              self.pcfg.max_seq_len
-                              - int(st.kv_len[slot]) - 1)
-                    props = [] if cap <= 0 else self.drafter.propose(
-                        slot, list(r.tokens) + outs[r.rid], cap)
-                    props = [int(t) for t in props[:max(cap, 0)]]
-                    drafts[slot, :len(props)] = props
-                    win[slot] = 1 + len(props)
-            #    grow block lists to cover the token(s) about to be written;
-            #    exhaustion first sheds the draft window (speculation must
-            #    never force an eviction), then under misprediction preempts
-            #    the slack-most resident (possibly the grower itself)
-            for slot in list(decoding):
-                if st.active[slot] is None:
+                if not any(a is not None for a in st.active):
+                    break
+                # b) one prefill chunk (chunked mode; unchunked prompts
+                #    complete inside _admit).  Multiple mid-prefill slots
+                #    take turns, so per-iteration prefill work stays <= one
+                #    chunk
+                if st.prefilling:
+                    pre_slots = sorted(st.prefilling)
+                    slot = pre_slots[rr % len(pre_slots)]
+                    rr += 1
+                    had_decoders = bool(st.decoding_slots())
+                    t0 = time.perf_counter()
+                    self._run_chunk(st, slot, outs, res)
+                    dt = time.perf_counter() - t0
+                    res.prefill_s += dt
+                    if had_decoders:
+                        res.prefill_stall_s += dt
+                        self._stalls.append(dt)
+                decoding = st.decoding_slots()
+                # just-admitted (or just-completed-prefill) sequences may
+                # already be at their stop count — let the fixpoint retire
+                # them before they join a decode step
+                decoding = [s for s in decoding
+                            if len(outs[st.active[s].rid]) < min(
+                                st.active[s].true_output_len, budget)]
+                if not decoding:
                     continue
-                while True:
-                    try:
-                        st.ensure_blocks(slot,
-                                         int(st.kv_len[slot])
-                                         + int(win[slot]),
-                                         self.pcfg.block_size)
-                        break
-                    except MemoryError:
-                        if win[slot] > 1:
-                            win[slot] = 1
-                            drafts[slot, :] = 0
+                # c) speculative draft window: propose *before* block growth
+                #    so the grower knows the full write horizon.  Per-slot
+                #    draft width is capped by the tokens the request may
+                #    still emit and by its block-table width, so a
+                #    near-finished or near-max_seq sequence never drafts past
+                #    its own end
+                k_spec = self.pcfg.spec_tokens
+                win = np.ones(self.pcfg.max_batch, np.int32)
+                drafts: Optional[np.ndarray] = None
+                if k_spec > 0:
+                    with phase("draft"):
+                        drafts = np.zeros((self.pcfg.max_batch, k_spec),
+                                          np.int32)
+                        win = np.zeros(self.pcfg.max_batch, np.int32)
+                        for slot in decoding:
+                            r = st.active[slot]
+                            m = min(r.true_output_len, budget) \
+                                - len(outs[r.rid])
+                            cap = min(k_spec, m - 1,
+                                      self.pcfg.max_seq_len
+                                      - int(st.kv_len[slot]) - 1)
+                            props = [] if cap <= 0 else self.drafter.propose(
+                                slot, list(r.tokens) + outs[r.rid], cap)
+                            props = [int(t) for t in props[:max(cap, 0)]]
+                            drafts[slot, :len(props)] = props
+                            win[slot] = 1 + len(props)
+                #    grow block lists to cover the token(s) about to be
+                #    written; exhaustion first sheds the draft window
+                #    (speculation must never force an eviction), then under
+                #    misprediction preempts the slack-most resident (possibly
+                #    the grower itself)
+                with phase("grow"):
+                    for slot in list(decoding):
+                        if st.active[slot] is None:
                             continue
-                        if not self.pcfg.preempt:
-                            raise MemoryError(
-                                "KV pool exhausted mid-decode (output "
-                                "longer than predicted); enable preempt "
-                                "to evict-and-recompute instead") from None
-                        now = time.perf_counter() - self._serve_t0
-                        victim = self._pick_victim(
-                            st, outs, min_slack=float("-inf"), now=now)
-                        if victim is None or (
-                                victim == slot and
-                                sum(a is not None for a in st.active) == 1):
-                            raise
-                        self._preempt(st, victim, outs, res, queue)
-                        if victim == slot:
-                            break
-            decoding = [s for s in decoding if st.active[s] is not None]
-            if not decoding:
-                continue
-            # d) KV gauges at the allocation high-water mark (post-growth)
-            live = st.live_blocks
-            res.peak_blocks = max(res.peak_blocks, live)
-            if live >= peak_live:
-                peak_live = live
-                peak_pool_stats = st.alloc.stats()
-            valid = int(st.kv_len[[i for i, a in enumerate(st.active)
-                                   if a is not None]].sum())
-            alloc_slots = live * self.pcfg.block_size
-            n_active = sum(a is not None for a in st.active)
-            if alloc_slots:
-                util_sum += valid / alloc_slots
-                waste_sum += 1.0 - alloc_slots / (n_active *
-                                                  self.pcfg.max_seq_len)
-                util_n += 1
-            # e) one fixed-shape decode step over all slots; mid-prefill
-            #    slots are masked to the null block (like free slots) so
-            #    their half-written KV is neither read nor clobbered.  With
-            #    speculation the step is a verify pass scoring the input
-            #    token plus the drafts in one multi-token kernel call
-            if k_spec > 0:
-                self._spec_step(st, decoding, outs, res, drafts, win)
-                steps += 1
-                continue
-            td0 = time.perf_counter()
-            bt, kv, ct = st.masked_decode_view()
-            logits, st.pools = self._decode(
-                self.params, jnp.asarray(ct)[:, None], st.pools,
-                jnp.asarray(bt), jnp.asarray(kv))
-            nxt = np.asarray(greedy(logits, self.cfg.vocab_size))
-            steps += 1
-            now = time.perf_counter()
-            for slot in decoding:
-                r = st.active[slot]
-                outs[r.rid].append(int(nxt[slot]))
-                st.cur_tok[slot] = int(nxt[slot])
-                st.kv_len[slot] += 1
-                prev = self._last_emit.get(slot)
-                if prev is not None:
-                    res.inter_token_s.append(now - prev)
-                self._last_emit[slot] = now
-                if self.tracer.enabled:
-                    self.tracer.span(
-                        "decode", td0 - self._serve_t0,
-                        now - self._serve_t0, track=self.track,
-                        row=slot_row(slot),
-                        args={"rid": r.rid, "token": int(nxt[slot]),
-                              "batch": len(decoding),
-                              "kv": float(np.mean(kv[decoding])),
-                              "q_tokens": 1})
-        jax.block_until_ready(st.pools)
-        # leak audit: every slot was finished or aborted, so the allocator
-        # must be down to exactly the reserved null block — proven zero
-        # leakage even across abort/preempt/speculative-rollback paths
-        leaks = st.alloc.check(expect_used=1)
-        if leaks:
-            raise RuntimeError(
-                "KV block leak after serve: " + "; ".join(leaks))
+                        while True:
+                            try:
+                                st.ensure_blocks(slot,
+                                                 int(st.kv_len[slot])
+                                                 + int(win[slot]),
+                                                 self.pcfg.block_size)
+                                break
+                            except MemoryError:
+                                if win[slot] > 1:
+                                    win[slot] = 1
+                                    drafts[slot, :] = 0
+                                    continue
+                                if not self.pcfg.preempt:
+                                    raise MemoryError(
+                                        "KV pool exhausted mid-decode "
+                                        "(output longer than predicted); "
+                                        "enable preempt to "
+                                        "evict-and-recompute instead"
+                                    ) from None
+                                now = time.perf_counter() - self._serve_t0
+                                victim = self._pick_victim(
+                                    st, outs, min_slack=float("-inf"),
+                                    now=now)
+                                if victim is None or (
+                                        victim == slot and sum(
+                                            a is not None
+                                            for a in st.active) == 1):
+                                    raise
+                                self._preempt(st, victim, outs, res, queue)
+                                if victim == slot:
+                                    break
+                decoding = [s for s in decoding if st.active[s] is not None]
+                if not decoding:
+                    continue
+                # d) KV gauges at the allocation high-water mark (post-growth)
+                with phase("gauges"):
+                    live = st.live_blocks
+                    res.peak_blocks = max(res.peak_blocks, live)
+                    if live >= peak_live:
+                        peak_live = live
+                        peak_pool_stats = st.alloc.stats()
+                    valid = int(st.kv_len[[i for i, a in enumerate(st.active)
+                                           if a is not None]].sum())
+                    alloc_slots = live * self.pcfg.block_size
+                    n_active = sum(a is not None for a in st.active)
+                    if alloc_slots:
+                        util_sum += valid / alloc_slots
+                        waste_sum += 1.0 - alloc_slots / (
+                            n_active * self.pcfg.max_seq_len)
+                        util_n += 1
+                # e) one fixed-shape decode step over all slots; mid-prefill
+                #    slots are masked to the null block (like free slots) so
+                #    their half-written KV is neither read nor clobbered.
+                #    With speculation the step is a verify pass scoring the
+                #    input token plus the drafts in one multi-token kernel
+                #    call
+                if k_spec > 0:
+                    self._spec_step(st, decoding, outs, res, drafts, win)
+                    steps += 1
+                    continue
+                td0 = time.perf_counter()
+                with phase("view"):
+                    bt, kv, ct = st.masked_decode_view()
+                    tok_d = jnp.asarray(ct)[:, None]
+                    bt_d, kv_d = jnp.asarray(bt), jnp.asarray(kv)
+                with phase("dispatch"):
+                    logits, st.pools = self._decode(
+                        self.params, tok_d, st.pools, bt_d, kv_d)
+                with phase("sample"):
+                    picked = greedy(logits, self.cfg.vocab_size)
+                with phase("sync"):
+                    nxt = np.asarray(picked)
+                with phase("emit"):
+                    steps += 1
+                    now = time.perf_counter()
+                    for slot in decoding:
+                        r = st.active[slot]
+                        outs[r.rid].append(int(nxt[slot]))
+                        st.cur_tok[slot] = int(nxt[slot])
+                        st.kv_len[slot] += 1
+                        prev = self._last_emit.get(slot)
+                        if prev is not None:
+                            res.inter_token_s.append(now - prev)
+                        self._last_emit[slot] = now
+                        if self.tracer.enabled:
+                            self.tracer.span(
+                                "decode", td0 - self._serve_t0,
+                                now - self._serve_t0, track=self.track,
+                                row=slot_row(slot),
+                                args={"rid": r.rid, "token": int(nxt[slot]),
+                                      "batch": len(decoding),
+                                      "kv": float(np.mean(kv[decoding])),
+                                      "q_tokens": 1})
+        with phase("drain"):
+            jax.block_until_ready(st.pools)
+            # leak audit: every slot was finished or aborted, so the allocator
+            # must be down to exactly the reserved null block — proven zero
+            # leakage even across abort/preempt/speculative-rollback paths
+            leaks = st.alloc.check(expect_used=1)
+            if leaks:
+                raise RuntimeError(
+                    "KV block leak after serve: " + "; ".join(leaks))
         res.decode_s = time.perf_counter() - t_total - res.prefill_s
         res.steps = steps
         res.outputs = outs

@@ -4,11 +4,15 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.obs.trace import device_scope
+
 
 def greedy(logits: jnp.ndarray, vocab_size: int) -> jnp.ndarray:
     """logits [B, Vp] -> [B] token ids, restricted to the real vocab."""
-    masked = jnp.where(jnp.arange(logits.shape[-1]) < vocab_size, logits, -jnp.inf)
-    return jnp.argmax(masked, axis=-1).astype(jnp.int32)
+    with device_scope("pick"):
+        masked = jnp.where(jnp.arange(logits.shape[-1]) < vocab_size, logits,
+                           -jnp.inf)
+        return jnp.argmax(masked, axis=-1).astype(jnp.int32)
 
 
 def sample(logits: jnp.ndarray, vocab_size: int, key, *, temperature: float = 1.0,

@@ -9,6 +9,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.core.types import Batch, Request
+from repro.obs import Tracer
 from repro.serving import PagedEngine, PagedEngineConfig, kv_block_bytes
 
 BS = 8          # KV block size used throughout
@@ -35,11 +36,12 @@ def _reqs(cfg, n=5, in_len=20, out_max=8, seed=5):
                  out=int(rng.integers(1, out_max + 1))) for i in range(n)]
 
 
-def _serve(cfg, params, reqs, **kw):
+def _serve(cfg, params, reqs, tracer=None, **kw):
     pcfg_kw = dict(max_batch=4, block_size=BS, n_blocks=64, max_seq_len=64,
                    max_new_tokens=12)
     pcfg_kw.update(kw)
-    eng = PagedEngine(cfg, params, PagedEngineConfig(**pcfg_kw))
+    eng = PagedEngine(cfg, params, PagedEngineConfig(**pcfg_kw),
+                      tracer=tracer)
     return eng.run_continuous([copy.copy(r) for r in reqs])
 
 
@@ -172,10 +174,15 @@ def test_admission_decisions_identical_with_hidden_truth(model):
         r.predicted_output_len = 6
         r.true_output_len = 1          # hidden truth collapses entirely
     kw = dict(n_blocks=12)                   # tight pool: admission matters
-    res_a = _serve(cfg, params, reqs_a, **kw)
-    res_b = _serve(cfg, params, reqs_b, **kw)
+    tr_a, tr_b = Tracer(), Tracer()
+    res_a = _serve(cfg, params, reqs_a, tracer=tr_a, **kw)
+    res_b = _serve(cfg, params, reqs_b, tracer=tr_b, **kw)
     assert res_a.peak_residents == res_b.peak_residents
-    assert res_a.hol_skips == res_b.hol_skips
+
+    def hol_skips(tr):
+        return sum(e.args["hol_skip"] > 0 for e in tr.events
+                   if e.name == "admitted")
+    assert hol_skips(tr_a) == hol_skips(tr_b)
 
 
 # --------------------------------------------------- null-block pool sizing

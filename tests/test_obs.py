@@ -14,6 +14,8 @@ from repro.obs import (EVENT_NAMES, INSTANT_NAMES, NULL_TRACER, SPAN_NAMES,
                        Tracer, check_invariants, export_trace,
                        metrics_payload, slot_row, to_chrome,
                        validate_metrics, validate_trace)
+from repro.obs.trace import (DEVICE_SCOPES, HOST_PHASES, PHASE_PREFIX,
+                             device_scope, phase)
 
 BS = 8
 
@@ -369,3 +371,66 @@ def test_engine_tracing_identity(model):
         bd = r.breakdown
         assert bd.e2e_s >= bd.ttft_s >= 0
         assert bd.prefill_s > 0
+
+
+# ------------------------------------------------ host phases in the profiler
+
+def _profiled_wave(engine, reqs, log_dir):
+    """Serve ``reqs`` once to compile, then again under ``jax.profiler``;
+    returns (result, [(name, start_ns, end_ns)] of the ``uellm/`` spans)."""
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+    engine.run_continuous([copy.copy(r) for r in reqs])
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        res = engine.run_continuous([copy.copy(r) for r in reqs])
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    spans = [(ev.name[len(PHASE_PREFIX):], ev.start_ns, ev.end_ns)
+             for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith(PHASE_PREFIX)]
+    return res, spans
+
+
+@pytest.mark.parametrize("mode", ["unchunked", "chunked", "speculative"])
+def test_engine_host_phases_in_profiler_trace(model, tmp_path, mode):
+    """Every decode or verify step leaves exactly one ``sync`` in the
+    profiler's trace; the step's scopes nest inside an ``iteration`` and
+    every prefill call leaves one ``prefill``; no name falls outside the
+    vocabulary."""
+    from repro.serving import PagedEngine, PagedEngineConfig
+    cfg, params = model
+    reqs = [_req(i, [3 + i] * (9 + 5 * i), out=3 + i % 3) for i in range(4)]
+    pcfg = PagedEngineConfig(
+        max_batch=2, block_size=BS, n_blocks=32, max_seq_len=48,
+        max_new_tokens=8, chunk_tokens=BS if mode == "chunked" else 0,
+        spec_tokens=2 if mode == "speculative" else 0)
+    res, spans = _profiled_wave(PagedEngine(cfg, params, pcfg), reqs,
+                                tmp_path)
+    names = [n for n, _, _ in spans]
+    assert set(names) <= HOST_PHASES
+    assert names.count("sync") == res.steps > 0
+    assert names.count("prefill") == res.prefill_chunks >= len(reqs)
+    assert names.count("drain") == 1
+    assert ("draft" in names) == (mode == "speculative")
+    iters = [(s, e) for n, s, e in spans if n == "iteration"]
+    for n, s, e in spans:
+        if n in ("sync", "view", "dispatch", "sample", "emit", "grow"):
+            assert any(a <= s and e <= b for a, b in iters), n
+
+
+def test_phase_names_are_checked():
+    """A scope outside the vocabulary is refused where it is opened."""
+    with phase("sync"):
+        pass
+    with pytest.raises(AssertionError):
+        phase("decode_step")
+    with pytest.raises(AssertionError):
+        device_scope("sync")
+    assert not HOST_PHASES & DEVICE_SCOPES

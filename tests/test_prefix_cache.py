@@ -12,6 +12,7 @@ from repro.core.scheduler import (SchedulerConfig, prefix_affinity_key,
                                   slo_odbs)
 from repro.core.types import Request
 from repro.data.workload import SharedPrefixConfig, gen_shared_prefix_requests
+from repro.obs import Tracer
 from repro.serving.kv_cache import BlockAllocator
 from repro.serving.prefix_cache import PrefixCache, RadixBlockTree
 
@@ -248,13 +249,20 @@ def model():
     return cfg, params
 
 
-def _serve(cfg, params, reqs, **pcfg_kw):
+def _serve(cfg, params, reqs, tracer=None, **pcfg_kw):
     from repro.serving import PagedEngine, PagedEngineConfig
     kw = dict(max_batch=4, block_size=BS, n_blocks=64, max_seq_len=64,
               max_new_tokens=12)
     kw.update(pcfg_kw)
-    eng = PagedEngine(cfg, params, PagedEngineConfig(**kw))
+    eng = PagedEngine(cfg, params, PagedEngineConfig(**kw), tracer=tracer)
     return eng.run_continuous([copy.copy(r) for r in reqs])
+
+
+def _hol_skips(tracer) -> int:
+    """Admissions that jumped a blocked queue head (``admitted`` instants
+    whose ``hol_skip`` is a queue index past the head)."""
+    return sum(e.args["hol_skip"] > 0 for e in tracer.events
+               if e.name == "admitted")
 
 
 def _template_reqs(cfg, n=6, tmpl_len=24, suffix=8, seed=7):
@@ -353,10 +361,13 @@ def test_admit_lookahead_skips_blocked_head(model):
     small = _req(2, rng.integers(0, cfg.vocab_size, 8).tolist(), out=4,
                  arrival=2.0)
     kw = dict(max_batch=2, n_blocks=7, max_seq_len=64, max_new_tokens=12)
-    fifo_run = _serve(cfg, params, [r0, big, small], admit_lookahead=0, **kw)
-    la_run = _serve(cfg, params, [r0, big, small], admit_lookahead=2, **kw)
-    assert fifo_run.hol_skips == 0
-    assert la_run.hol_skips >= 1           # small jumped the blocked head
+    fifo_tr, la_tr = Tracer(), Tracer()
+    fifo_run = _serve(cfg, params, [r0, big, small], tracer=fifo_tr,
+                      admit_lookahead=0, **kw)
+    la_run = _serve(cfg, params, [r0, big, small], tracer=la_tr,
+                    admit_lookahead=2, **kw)
+    assert _hol_skips(fifo_tr) == 0
+    assert _hol_skips(la_tr) >= 1          # small jumped the blocked head
     assert fifo_run.outputs == la_run.outputs  # greedy streams unaffected
     assert set(la_run.outputs) == {0, 1, 2}
 
