@@ -49,6 +49,8 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core.monitor import Monitor
 from repro.core.types import Request
+from repro.kernels.paged_attention.paged_attention import (bucket_nb,
+                                                          live_pages)
 from repro.models import api
 from repro.serving.engine import BatchResult
 from repro.obs.trace import (NULL_TRACER, ROW_QUEUE, LatencyBreakdown,
@@ -146,6 +148,9 @@ class PagedBatchResult(BatchResult):
     drafted_tokens: int = 0        # draft positions scored by verify passes
     accepted_tokens: int = 0       # drafts matching the target's greedy pick
     spec_rolled_blocks: int = 0    # rejected-tail blocks rolled back
+    # --- paged-kernel reads, summed over decode/verify steps and slots ---
+    kv_pages_read: int = 0         # table pages the kernel's bound reads
+    kv_pages_table: int = 0        # bucketed table width: a whole-table walk
     # --- abort safety (fault tolerance) ---
     aborted: int = 0               # requests aborted mid-flight
     errors: dict = field(default_factory=dict)
@@ -727,6 +732,15 @@ class PagedEngine:
                       "total": len(pg.prompt),
                       "recompute": pg.recompute_from is not None})
 
+    def _count_pages(self, res: PagedBatchResult, bt: np.ndarray,
+                     kv: np.ndarray, t_span: int) -> None:
+        """Pages of the block table the paged kernel reads this step (each
+        slot's window of ``t_span`` queries starts at its ``kv``), against
+        the bucketed table it is handed."""
+        res.kv_pages_read += int(
+            live_pages(kv, t_span, self.pcfg.block_size).sum())
+        res.kv_pages_table += kv.size * bucket_nb(bt.shape[1])
+
     # ------------------------------------------------------------ speculative
     def _spec_step(self, st: PagedDecodeState, decoding: list, outs: dict,
                    res: PagedBatchResult, drafts: np.ndarray,
@@ -748,6 +762,7 @@ class PagedEngine:
         ts0 = time.perf_counter()
         with phase("view"):
             bt, kv, ct = st.masked_decode_view()
+            self._count_pages(res, bt, kv, t_w)
             win_eff = np.zeros(b, np.int32)
             for slot in decoding:
                 win_eff[slot] = win[slot]
@@ -1062,6 +1077,7 @@ class PagedEngine:
                 td0 = time.perf_counter()
                 with phase("view"):
                     bt, kv, ct = st.masked_decode_view()
+                    self._count_pages(res, bt, kv, 1)
                     tok_d = jnp.asarray(ct)[:, None]
                     bt_d, kv_d = jnp.asarray(bt), jnp.asarray(kv)
                 with phase("dispatch"):

@@ -2,15 +2,17 @@
 block-table gather parity against the contiguous decode oracle, across the xla / pallas-interpret backends, with
 padded (null-block) table tails; multi-token window parity (speculative
 verification) and the power-of-two block-table bucketing that caps jit
-specialization churn."""
+specialization churn.  Head dims below 128 take the page walk; the
+live-page sweep (head dim 128) is checked on bf16 pools at the edges of a
+compute block, and against NaN-filled dead blocks."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels.decode_attention.ref import decode_attention_reference
 from repro.kernels.paged_attention.paged_attention import (
-    _paged_window_core, bucket_nb, paged_decode_attention_pallas,
-    paged_window_attention_pallas)
+    _paged_window_core, bucket_nb, live_pages, pages_per_block,
+    paged_decode_attention_pallas, paged_window_attention_pallas)
 from repro.kernels.paged_attention.ref import (
     gather_pool, paged_decode_attention_reference,
     paged_window_attention_reference)
@@ -235,3 +237,90 @@ def test_paged_reads_through_permuted_tables(rng):
         outs.append(np.asarray(paged_decode_attention_xla(
             q, kp, vp, jnp.asarray(bt), jnp.asarray(kv_len))))
     np.testing.assert_allclose(outs[0], outs[1], atol=2e-5, rtol=2e-5)
+
+
+# ------------------------------------- live-page sweep (lane-wide head dims)
+
+BS_SWEEP, NB_SWEEP, KV_SWEEP, D_SWEEP = 8, 32, 2, 128
+PPC = pages_per_block(BS_SWEEP, NB_SWEEP)
+# one row per edge of the sweep: a single key, one short of / exactly / one
+# past a compute block, and the whole bucketed table
+SWEEP_LENGTHS = [1, PPC * BS_SWEEP - 1, PPC * BS_SWEEP, PPC * BS_SWEEP + 1,
+                 NB_SWEEP * BS_SWEEP]
+
+
+def _sweep_case(rng, group, t, softcap, poison):
+    """bf16 pools laid out as the engine lays them: block 0 is the null
+    block, each row's table lists its live blocks and then the null block.
+    Row i attends ``SWEEP_LENGTHS[i]`` keys (its window's last position
+    included).  With ``poison`` every physical block outside the rows' live
+    prefixes, the null block among them, is NaN.  Returns the kernel's
+    output and the reference's, which reads the clean pools."""
+    b, h = len(SWEEP_LENGTHS), group * KV_SWEEP
+    base = (np.maximum(np.array(SWEEP_LENGTHS), t) - t).astype(np.int32)
+    n = 1 + b * NB_SWEEP
+    kp, vp = (rng.standard_normal((KV_SWEEP, n, BS_SWEEP, D_SWEEP)).astype(
+        jnp.bfloat16) for _ in range(2))
+    owned = 1 + rng.permutation(n - 1).reshape(b, NB_SWEEP)
+    live = live_pages(base, t, BS_SWEEP)
+    bt = np.zeros((b, NB_SWEEP), np.int32)
+    for i in range(b):
+        bt[i, :live[i]] = owned[i, :live[i]]
+    q = rng.standard_normal((b, t, h, D_SWEEP)).astype(np.float32)
+    ref = paged_window_attention_reference(
+        q, jnp.asarray(kp), jnp.asarray(vp), bt, base, softcap=softcap)
+    if poison:
+        dead = np.setdiff1d(np.arange(n), bt[bt > 0])
+        assert 0 in dead
+        kp[:, dead] = np.nan
+        vp[:, dead] = np.nan
+    out = paged_window_attention_pallas(
+        q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(bt),
+        jnp.asarray(base), softcap=softcap, interpret=True)
+    return np.asarray(out), np.asarray(ref), (q, kp, vp, bt, base)
+
+
+@pytest.mark.parametrize("softcap", [None, 30.0])
+@pytest.mark.parametrize("t", [1, 4])
+@pytest.mark.parametrize("group", [6, 16])
+def test_sweep_matches_reference_at_block_edges(rng, group, t, softcap):
+    """Lane-wide heads take the live-page sweep: parity with the reference
+    on bf16 pools at every edge of a compute block, for the groups of
+    qwen2-1.5b (6) and chatglm2-6b (16), decode and a verify window."""
+    out, ref, (q, kp, vp, bt, base) = _sweep_case(rng, group, t, softcap,
+                                                  poison=False)
+    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+    if t == 1:
+        single = paged_decode_attention_pallas(
+            q[:, 0], jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(bt),
+            jnp.asarray(base) + 1, softcap=softcap, interpret=True)
+        np.testing.assert_array_equal(np.asarray(single), out[:, 0])
+
+
+@pytest.mark.parametrize("t", [1, 4])
+@pytest.mark.parametrize("group", [6, 16])
+def test_sweep_never_reads_dead_pages(rng, group, t):
+    """Every block outside the live prefixes, the null block included, is
+    NaN, and the output is finite and the reference's; with the dead table
+    entries pointing past the end of the pool the output is unchanged."""
+    out, ref, (q, kp, vp, bt, base) = _sweep_case(rng, group, t, 30.0,
+                                                  poison=True)
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+    # a masked read would hide a NaN; a table entry past the end of the
+    # pool cannot be copied at all (the interpreter raises on it)
+    past_end = np.where(bt > 0, bt, kp.shape[1]).astype(np.int32)
+    out2 = paged_window_attention_pallas(
+        q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(past_end),
+        jnp.asarray(base), softcap=30.0, interpret=True)
+    np.testing.assert_array_equal(np.asarray(out2), out)
+
+
+def test_live_pages_bounds_the_sweep():
+    """The page count the engine reports is the kernel's own bound."""
+    base = np.array([-1, 0, 7, 8, 120], np.int32)
+    np.testing.assert_array_equal(live_pages(base, 1, 8), [0, 1, 1, 2, 16])
+    np.testing.assert_array_equal(live_pages(base, 4, 8), [1, 1, 2, 2, 16])
+    assert pages_per_block(8, 256) == 16
+    assert pages_per_block(8, 4) == 4
+    assert pages_per_block(16, 256) == 8

@@ -132,6 +132,31 @@ def test_single_token_request_admitted_mid_run(engines):
         assert res_p.outputs[r.rid] == res.outputs[r.rid], r.rid
 
 
+def test_kv_page_counters_sum_the_kernels_reads(engines):
+    """``kv_pages_read`` sums, over decode steps and slots, the table pages
+    the paged kernel's bound reads: a slot at history length kv reads the
+    pages holding positions 0..kv (the new token's included), a free slot
+    its null block.  ``kv_pages_table`` is what a walk of the whole bucketed
+    table reads."""
+    cfg, eng, _ = engines
+    pcfg = PagedEngineConfig(max_batch=2, block_size=BS, n_blocks=32,
+                             max_seq_len=64, max_new_tokens=12)
+    peng = PagedEngine(cfg, eng.params, pcfg)
+    reqs = _reqs(cfg, 2)
+    for r, n in zip(reqs, (10, 5)):
+        r.tokens, r.input_len = r.tokens[:n], n
+    reqs[0].true_output_len, reqs[1].true_output_len = 4, 2
+    res = peng.run_continuous(reqs)
+    # prefill emits each first token; the decode steps then run at history
+    # lengths A: 10, 11, 12 and B: 5 (its slot is free, kv 0, after that)
+    assert res.steps == 3
+    # pages (kv + 1) / 8 rounded up: step 1 A 2 + B 1, steps 2-3 A 2 + 1
+    assert res.kv_pages_read == 3 + 3 + 3
+    # 3 steps x 2 slots x 8-block table (64 / 8, already a power of two)
+    assert res.kv_pages_table == 3 * 2 * 8
+    assert res.kv_pages_read <= res.kv_pages_table
+
+
 def test_request_larger_than_pool_rejected(engines):
     from repro.core.types import Request
     cfg, eng, _ = engines

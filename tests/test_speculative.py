@@ -191,6 +191,35 @@ def test_spec_rejection_rolls_back_blocks(model):
     assert res.iterations_per_token >= 0.9 * 1 / 3  # no free lunch
 
 
+def test_spec_kv_page_counters_count_the_window(model):
+    """A verify step's kernel reads the pages holding positions up to the
+    end of its whole window (spec_tokens + 1 queries from kv_len), whatever
+    a slot's own draft width."""
+    cfg, params = model
+
+    class Rejected:
+        """Drafts the one token never picked: one token emitted a step."""
+        name = "rejected"
+
+        def propose(self, slot, history, k):
+            return [cfg.vocab_size - 1] * k
+
+        def release(self, slot):
+            pass
+
+    req = _reqs(cfg, n=1)[0]
+    req.true_output_len = 6
+    eng = PagedEngine(cfg, params, PagedEngineConfig(
+        max_batch=1, block_size=4, n_blocks=32, max_seq_len=64,
+        max_new_tokens=12, spec_tokens=8), drafter=Rejected())
+    res = eng.run_continuous([req])
+    # prompt 20; prefill emits token 1, verify steps at kv 20..24 emit 2..6
+    assert res.steps == 5
+    # pages (kv + 9) / 4 rounded up: 8, 8, 8, 8, 9
+    assert res.kv_pages_read == 41
+    assert res.kv_pages_table == 5 * 16        # 64 / 4 blocks, one slot
+
+
 def test_spec_steps_drop_on_draftable_workload(model):
     cfg, params = model
     reqs = _reqs(cfg, n=6, out_lo=8, out_hi=12)

@@ -1,6 +1,7 @@
 """Ahead-of-time compiles for a described TPU v5e chip: every Pallas kernel
-at the published widths of qwen2-1.5b and smollm-135m, and whole jitted
-paged-decode and prefill steps of qwen2-1.5b.  Nothing runs — the TPU's
+at the published widths of qwen2-1.5b and smollm-135m, the paged kernels
+at chatglm2-6b's, and whole jitted paged-decode and prefill steps of
+qwen2-1.5b.  Nothing runs — the TPU's
 compiler refuses what the chip would refuse (block shapes it cannot tile,
 too much fast memory, a program larger than the device), which interpret
 mode never shows.
@@ -97,6 +98,17 @@ def test_kernel_compiles_for_v5e(spec, kernel, arch, dtype):
     cfg = get_config(arch)
     fn, args = _kernel_call(kernel, spec, cfg.n_heads, cfg.n_kv_heads,
                             cfg.head_dim_eff, dtype)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kernel", ["paged_decode", "paged_window"])
+def test_paged_kernel_compiles_for_v5e_at_chatglm2_widths(spec, kernel):
+    """chatglm2-6b's 32 query heads over 2 kv heads (group 16, 16 rows a
+    kv head) at head dim 128, bf16: the live-page sweep at its widest."""
+    cfg = get_config("chatglm2-6b")
+    fn, args = _kernel_call(kernel, spec, cfg.n_heads, cfg.n_kv_heads,
+                            cfg.head_dim_eff, jnp.bfloat16)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
