@@ -87,6 +87,8 @@ class ModelConfig:
     qkv_bias: bool = False
     rope: Literal["rope", "mrope", "none"] = "rope"
     rope_theta: float = 10_000.0
+    rope_fraction: float = 1.0             # share of a q/k head rotated
+    rope_interleaved: bool = False         # rotate pairs (2i, 2i+1), not halves
     mrope_sections: tuple[int, int, int] = (16, 24, 24)
     attn_softcap: Optional[float] = None
     final_softcap: Optional[float] = None
@@ -114,6 +116,18 @@ class ModelConfig:
     vocab_pad_mult: int = 256
     dtype: str = "bfloat16"
     source: str = ""                       # provenance tag from the assignment
+
+    def __post_init__(self):
+        if self.rope_fraction == 1.0 and not self.rope_interleaved:
+            return
+        if self.rope != "rope" or self.mla is not None:
+            raise ValueError(f"{self.name}: rope_fraction / rope_interleaved "
+                             "apply to plain rotary heads only")
+        r = int(self.rope_fraction * self.head_dim_eff)
+        if r <= 0 or r % 2 or r > self.head_dim_eff:
+            raise ValueError(f"{self.name}: rope_fraction {self.rope_fraction}"
+                             f" rotates {r} of {self.head_dim_eff} channels; "
+                             "need an even count, at least 2")
 
     # ------------------------------------------------------------------
     @property

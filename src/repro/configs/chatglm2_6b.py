@@ -17,6 +17,8 @@ CONFIG = ModelConfig(
     qkv_bias=True,
     rope="rope",
     rope_theta=10_000.0,
+    rope_fraction=0.5,         # RotaryEmbedding(kv_channels // 2): channels 0-63
+    rope_interleaved=True,     # apply_rotary_pos_emb rotates pairs (2i, 2i+1)
     act="silu",
     source="hf:THUDM/chatglm2-6b; hf (paper §5.1 model)",
 )

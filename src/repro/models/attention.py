@@ -61,14 +61,16 @@ def _qkv(cfg: ModelConfig, p, x, positions):
     q = apply_dense(p["q"], x).reshape(b, s, h, hd)
     k = apply_dense(p["k"], x).reshape(b, s, kv, hd)
     v = apply_dense(p["v"], x).reshape(b, s, kv, cfg.v_head_dim_eff)
-    if cfg.rope == "rope":
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
-    elif cfg.rope == "mrope":
-        pos3 = positions if positions.ndim == 3 else jnp.broadcast_to(
-            positions, (3,) + positions.shape)
-        q = apply_mrope(q, pos3, cfg.rope_theta, cfg.mrope_sections)
-        k = apply_mrope(k, pos3, cfg.rope_theta, cfg.mrope_sections)
+    with device_scope("rope"):
+        if cfg.rope == "rope":
+            rot = (cfg.rope_theta, cfg.rope_fraction, cfg.rope_interleaved)
+            q = apply_rope(q, positions, *rot)
+            k = apply_rope(k, positions, *rot)
+        elif cfg.rope == "mrope":
+            pos3 = positions if positions.ndim == 3 else jnp.broadcast_to(
+                positions, (3,) + positions.shape)
+            q = apply_mrope(q, pos3, cfg.rope_theta, cfg.mrope_sections)
+            k = apply_mrope(k, pos3, cfg.rope_theta, cfg.mrope_sections)
     return q, k, v
 
 
@@ -81,11 +83,14 @@ def _mla_qkv(cfg: ModelConfig, p, x, positions):
     q = apply_dense(p["q_up"], apply_dense(p["q_down"], x))
     q = q.reshape(b, s, h, m.qk_head_dim)
     q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    with device_scope("rope"):
+        q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     down = apply_dense(p["kv_down"], x)
     c_kv, k_rope = jnp.split(down, [m.kv_lora_rank], axis=-1)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+    with device_scope("rope"):
+        k_rope = apply_rope(k_rope[:, :, None, :], positions,
+                            cfg.rope_theta)[:, :, 0]
     return q, c_kv, k_rope
 
 

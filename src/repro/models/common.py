@@ -8,6 +8,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 
@@ -70,9 +71,21 @@ def rope_freqs(head_dim: int, theta: float):
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jnp.ndarray, pos: jnp.ndarray, theta: float):
-    """x: [..., S, H, D]; pos: broadcastable to [..., S] absolute positions."""
+def apply_rope(x: jnp.ndarray, pos: jnp.ndarray, theta: float,
+               fraction: float = 1.0, interleaved: bool = False):
+    """x: [..., S, H, D]; pos: broadcastable to [..., S] absolute positions.
+
+    Rotates the first ``r = int(fraction * D)`` channels at frequencies
+    ``theta ** (-2i / r)`` and passes the rest through: channel i against
+    i + r/2 (the two halves), or with ``interleaved`` channel 2i against
+    2i + 1 (ChatGLM2's ``apply_rotary_pos_emb``)."""
     d = x.shape[-1]
+    r = int(fraction * d)
+    if interleaved:
+        return _rope_pairs(x, pos, theta, r)
+    if r < d:
+        return jnp.concatenate([apply_rope(x[..., :r], pos, theta),
+                                x[..., r:]], axis=-1)
     freqs = rope_freqs(d, theta)                       # [D/2]
     angles = pos[..., None].astype(jnp.float32) * freqs   # [..., S, D/2]
     cos = jnp.cos(angles)[..., None, :]                # [..., S, 1, D/2]
@@ -80,6 +93,24 @@ def apply_rope(x: jnp.ndarray, pos: jnp.ndarray, theta: float):
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
+
+
+def _rope_pairs(x: jnp.ndarray, pos: jnp.ndarray, theta: float, r: int):
+    """Pairs (2i, 2i+1) of the first r channels rotated, over the whole
+    width so that no size-2 axis lands on the TPU's lanes: each pair's
+    angle is repeated on both lanes (0 past r: cos 1, sin 0 pass the rest
+    through unchanged), and each lane's partner, -x[2i+1] or x[2i], comes
+    from a lane shift either way and a parity select."""
+    d = x.shape[-1]
+    freqs = jnp.pad(jnp.repeat(rope_freqs(r, theta), 2), (0, d - r))   # [D]
+    angles = pos[..., None].astype(jnp.float32) * freqs   # [..., S, D]
+    cos = jnp.cos(angles)[..., None, :]                # [..., S, 1, D]
+    sin = jnp.sin(angles)[..., None, :]
+    xf = x.astype(jnp.float32)
+    even = np.arange(d) % 2 == 0
+    partner = jnp.where(even, -jnp.roll(xf, -1, axis=-1),
+                        jnp.roll(xf, 1, axis=-1))
+    return (xf * cos + partner * sin).astype(x.dtype)
 
 
 def apply_mrope(x: jnp.ndarray, pos3: jnp.ndarray, theta: float,

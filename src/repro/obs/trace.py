@@ -69,6 +69,7 @@ belong to:
     device scope     | covers
     -----------------+---------------------------------------------------
     attention        | q/k/v projection, the paged kernel, o projection
+    rope             | the rotary positions of q and k (inside attention)
     kv_write         | the new token's K/V scattered into the pool
     mlp              | the feed-forward block
     head             | final norm and the LM head
@@ -115,7 +116,8 @@ HOST_PHASES = frozenset({
     "iteration", "admit", "prefill", "finish", "grow", "gauges", "draft",
     "view", "dispatch", "sample", "sync", "emit", "drain",
 })
-DEVICE_SCOPES = frozenset({"attention", "kv_write", "mlp", "head", "pick"})
+DEVICE_SCOPES = frozenset({"attention", "rope", "kv_write", "mlp", "head",
+                           "pick"})
 PHASE_PREFIX = "uellm/"
 
 

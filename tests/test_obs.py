@@ -434,3 +434,35 @@ def test_phase_names_are_checked():
     with pytest.raises(AssertionError):
         device_scope("sync")
     assert not HOST_PHASES & DEVICE_SCOPES
+
+
+@pytest.mark.parametrize("arch", ["chatglm2-6b", "qwen2-1.5b"])
+def test_rope_scope_names_the_rotation_in_the_decode_step(arch):
+    """In the compiled paged decode step every cos and sin, and for
+    ChatGLM2's pairs the negation, lane shifts and parity select, carry
+    ``attention/rope`` in their op metadata, the scope path the profiler
+    reports as ``tf_op``."""
+    import functools
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from repro.models import api
+    cfg = get_config(arch).reduced()
+    shape = lambda f: jax.eval_shape(f)   # noqa: E731
+    params = shape(lambda: api.init_params(cfg, jax.random.PRNGKey(0),
+                                           jnp.float32))
+    pools = shape(lambda: api.init_paged_pools(cfg, 8, 4, jnp.float32))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    hlo = jax.jit(functools.partial(api.paged_decode_step, cfg)).lower(
+        params, i32(2, 1), pools, i32(2, 4), i32(2)).compile().as_text()
+    scoped: dict = {}
+    for line in hlo.splitlines():
+        m = re.search(r"= \S+ (\w[\w-]*)\(.*op_name=\"([^\"]*)\"", line)
+        if m:
+            scoped.setdefault(m.group(1), set()).add(
+                "/attention/rope/" in m.group(2))
+    assert scoped["cosine"] == scoped["sine"] == {True}
+    if cfg.rope_interleaved:
+        assert True in scoped["negate"] and True in scoped["select"]
+        assert True in scoped["concatenate"]

@@ -1,7 +1,7 @@
 """Ahead-of-time compiles for a described TPU v5e chip: every Pallas kernel
 at the published widths of qwen2-1.5b and smollm-135m, the paged kernels
 at chatglm2-6b's, and whole jitted paged-decode and prefill steps of
-qwen2-1.5b.  Nothing runs — the TPU's
+qwen2-1.5b, and chatglm2-6b's paged-decode step.  Nothing runs — the TPU's
 compiler refuses what the chip would refuse (block shapes it cannot tile,
 too much fast memory, a program larger than the device), which interpret
 mode never shows.
@@ -113,13 +113,13 @@ def test_paged_kernel_compiles_for_v5e_at_chatglm2_widths(spec, kernel):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _params_and_pools(spec, cfg):
+def _params_and_pools(spec, cfg, dtype=jnp.float32):
     place = lambda tree: jax.tree.map(  # noqa: E731
         lambda x: spec(x.shape, x.dtype), tree)
     params = jax.eval_shape(
-        lambda: api.init_params(cfg, jax.random.PRNGKey(0), jnp.float32))
+        lambda: api.init_params(cfg, jax.random.PRNGKey(0), dtype))
     pools = jax.eval_shape(
-        lambda: api.init_paged_pools(cfg, N_BLOCKS, BS, jnp.float32))
+        lambda: api.init_paged_pools(cfg, N_BLOCKS, BS, dtype))
     return place(params), place(pools)
 
 
@@ -142,6 +142,25 @@ def test_paged_decode_step_compiles_for_v5e(spec):
                               spec((B, NB), jnp.int32),
                               spec((B,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    assert _fits_one_chip(compiled)
+
+
+def test_chatglm2_decode_step_compiles_for_v5e(spec):
+    """The served chatglm2-6b decode step at full width in bfloat16, as its
+    benchmark cell serves it: the paged kernel is in, the half-head rotary
+    in pairs is in the ``attention/rope`` scope, and 12.5 GB of weights
+    with the pool fit one chip."""
+    cfg = get_config("chatglm2-6b")
+    params, pools = _params_and_pools(spec, cfg, jnp.bfloat16)
+    step = jax.jit(functools.partial(api.paged_decode_step, cfg),
+                   donate_argnums=(2,))
+    with use_backend("pallas"):
+        compiled = step.lower(params, spec((B, 1), jnp.int32), pools,
+                              spec((B, NB), jnp.int32),
+                              spec((B,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "attention/rope/" in text
     assert _fits_one_chip(compiled)
 
 
