@@ -13,7 +13,10 @@ Two entry points share one core, ``_paged_window_core``:
 
 Pools are head-major, ``[KV, N, bs, D]``: the TPU compiler tiles the last
 two dimensions of a block by (8, 128) unless they span the whole array, so
-the kv-head axis must not be one of them.
+the kv-head axis must not be one of them.  The model step hands the kernel
+the whole layer-stacked pool ``[L, KV, N, bs, D]`` and the layer to read
+(a third scalar prefetch), so no layer's pool is ever sliced out and
+copied; a 4-D pool is read as layer 0 of a stack of one.
 
 Live-page sweep (head dims a multiple of 128).  Grid (batch, kv_head); the
 pools stay in HBM (``memory_space=ANY``) and the kernel copies K/V pages
@@ -156,7 +159,7 @@ def _finalize(o_ref, l_ref, acc_ref):
     o_ref[0, 0, :, :] = out.astype(o_ref.dtype)
 
 
-def _sweep_kernel(kv_len_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref,
+def _sweep_kernel(kv_len_ref, bt_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
                   k_buf, v_buf, k_sems, v_sems, state_ref,
                   m_ref, l_ref, acc_ref, *, scale: float,
                   softcap: Optional[float], block_size: int, ppc: int,
@@ -165,9 +168,10 @@ def _sweep_kernel(kv_len_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref,
     of ``ppc`` pages and stops at the last live one; dead pages cost no
     copy, no compute and no grid step.
 
-    The pools stay in HBM.  Each live page is copied into a double-buffered
-    VMEM scratch, and the next block's copies (this sequence's, or the first
-    block of the next grid step) start before the current block is computed.
+    The stacked pools stay in HBM.  Each live page of layer ``layer_ref[0]``
+    is copied into a double-buffered VMEM scratch, and the next block's
+    copies (this sequence's, or the first block of the next grid step)
+    start before the current block is computed.
     ``state_ref`` carries across grid steps: [0] the buffer the current
     block lands in, [1] whether the step before already started this step's
     first block."""
@@ -175,6 +179,7 @@ def _sweep_kernel(kv_len_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref,
     n_b, n_h = pl.num_programs(0), pl.num_programs(1)
     bk = ppc * block_size
     base = kv_len_ref[b]           # history length before the query window
+    layer = layer_ref[0]
     n_blk = (live_pages(base, t_span, block_size) + ppc - 1) // ppc
 
     def live_page_loop(bb, i, fn):
@@ -190,8 +195,8 @@ def _sweep_kernel(kv_len_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     def copy(which, hh, slot, j, page):
         hbm, buf, sems = pools[which]
-        return pltpu.make_async_copy(hbm.at[hh, page], buf.at[slot, j],
-                                     sems.at[slot])
+        return pltpu.make_async_copy(hbm.at[layer, hh, page],
+                                     buf.at[slot, j], sems.at[slot])
 
     def start(bb, hh, i, slot):
         def both(j, page):
@@ -249,7 +254,7 @@ def _sweep_kernel(kv_len_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref,
     _finalize(o_ref, l_ref, acc_ref)
 
 
-def _page_kernel(kv_len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
+def _page_kernel(kv_len_ref, bt_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
                  m_ref, l_ref, acc_ref, *, scale: float,
                  softcap: Optional[float], block_size: int, nb: int,
                  gp: int, t_span: int):
@@ -267,9 +272,9 @@ def _page_kernel(kv_len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(ki < live_pages(base, t_span, block_size))
     def _compute():
         q = q_ref[0, 0, :, :].astype(jnp.float32) * scale
-        _attend(q, k_ref[0, 0, :, :].astype(jnp.float32),
-                v_ref[0, 0, :, :].astype(jnp.float32), ki * block_size, base,
-                m_ref, l_ref, acc_ref, softcap=softcap, gp=gp)
+        _attend(q, k_ref[0, 0, 0, :, :].astype(jnp.float32),
+                v_ref[0, 0, 0, :, :].astype(jnp.float32), ki * block_size,
+                base, m_ref, l_ref, acc_ref, softcap=softcap, gp=gp)
 
     @pl.when(ki == nb - 1)
     def _end():
@@ -281,10 +286,11 @@ def _page_kernel(kv_len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
     static_argnames=("t_span", "group", "softcap", "scale", "interpret"))
 def _paged_window_core(
     q: jnp.ndarray,              # [B, T, H, D]
-    k_pool: jnp.ndarray,         # [KV, N, bs, D]
-    v_pool: jnp.ndarray,         # [KV, N, bs, Dv]
+    k_pool: jnp.ndarray,         # [L, KV, N, bs, D]
+    v_pool: jnp.ndarray,         # [L, KV, N, bs, Dv]
     block_tables: jnp.ndarray,   # [B, nb] int32 (pre-bucketed by the wrapper)
     kv_len: jnp.ndarray,         # [B] int32 — history BEFORE the window
+    layer: jnp.ndarray,          # [1] int32 — the layer of the pools to read
     *,
     t_span: int,
     group: int,
@@ -293,7 +299,7 @@ def _paged_window_core(
     interpret: bool,
 ) -> jnp.ndarray:
     b, t, h, d = q.shape
-    kv, _, bs, dv = v_pool.shape
+    _, kv, _, bs, dv = v_pool.shape
     nb = block_tables.shape[1]
     scale = scale if scale is not None else d ** -0.5
     gp = _group_pad(t, group)
@@ -318,7 +324,7 @@ def _paged_window_core(
         ppc = pages_per_block(bs, nb)
         kernel = functools.partial(_sweep_kernel, ppc=ppc, **kw)
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,          # kv_len, block_tables
+            num_scalar_prefetch=3,          # kv_len, block_tables, layer
             grid=(b, kv),
             in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY)],
@@ -333,12 +339,13 @@ def _paged_window_core(
         )
     else:
         kernel = functools.partial(_page_kernel, nb=nb, **kw)
-        page = lambda bi, hi, ki, kvl, bt: (hi, bt[bi, ki], 0, 0)  # noqa: E731
+        page = lambda bi, hi, ki, kvl, bt, ly: (  # noqa: E731
+            ly[0], hi, bt[bi, ki], 0, 0)
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(b, kv, nb),
-            in_specs=[q_spec, pl.BlockSpec((1, 1, bs, d), page),
-                      pl.BlockSpec((1, 1, bs, dv), page)],
+            in_specs=[q_spec, pl.BlockSpec((1, 1, 1, bs, d), page),
+                      pl.BlockSpec((1, 1, 1, bs, dv), page)],
             out_specs=o_spec,
             scratch_shapes=acc,
         )
@@ -351,17 +358,24 @@ def _paged_window_core(
             dimension_semantics=("arbitrary",) * len(grid_spec.grid)),
         interpret=pltpu.InterpretParams() if interpret else False,
     )(kv_len.astype(jnp.int32), block_tables.astype(jnp.int32),
-      qg, k_pool, v_pool)
+      layer.astype(jnp.int32), qg, k_pool, v_pool)
     out = out.reshape(b, kv, t, gp, dv)[:, :, :, :group, :]
     return jnp.moveaxis(out, 2, 1).reshape(b, t, h, dv)
 
 
+def _stacked(pool: jnp.ndarray) -> jnp.ndarray:
+    """The layer-stacked ``[L, KV, N, bs, D]`` view the core reads; a 4-D
+    pool is layer 0 of a stack of one (a free reshape)."""
+    return pool[None] if pool.ndim == 4 else pool
+
+
 def paged_window_attention_pallas(
     q: jnp.ndarray,              # [B, T, H, D] — the draft window
-    k_pool: jnp.ndarray,         # [KV, N, bs, D]
-    v_pool: jnp.ndarray,         # [KV, N, bs, Dv]
+    k_pool: jnp.ndarray,         # [L, KV, N, bs, D] or [KV, N, bs, D]
+    v_pool: jnp.ndarray,         # [L, KV, N, bs, Dv] or [KV, N, bs, Dv]
     block_tables: jnp.ndarray,   # [B, nb] int32
     kv_len: jnp.ndarray,         # [B] int32 — history length BEFORE the window
+    layer=0,                     # int32 scalar — the stacked pools' layer
     *,
     softcap: Optional[float] = None,
     scale: Optional[float] = None,
@@ -369,20 +383,23 @@ def paged_window_attention_pallas(
 ) -> jnp.ndarray:
     """Multi-token paged attention: window position t (absolute ``kv_len+t``,
     K/V already scattered at ``kv_len .. kv_len+T-1``) attends to cache
-    positions ``<= kv_len + t``.  Returns [B, T, H, Dv]."""
-    group = q.shape[2] // k_pool.shape[0]
+    positions ``<= kv_len + t`` of layer ``layer``.  Returns [B, T, H, Dv]."""
+    k_pool, v_pool = _stacked(k_pool), _stacked(v_pool)
+    group = q.shape[2] // k_pool.shape[1]
     return _paged_window_core(
         q, k_pool, v_pool, _pad_tables(block_tables),
-        jnp.asarray(kv_len, jnp.int32), t_span=q.shape[1], group=group,
-        softcap=softcap, scale=scale, interpret=interpret)
+        jnp.asarray(kv_len, jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1), t_span=q.shape[1],
+        group=group, softcap=softcap, scale=scale, interpret=interpret)
 
 
 def paged_decode_attention_pallas(
     q: jnp.ndarray,              # [B, H, D]
-    k_pool: jnp.ndarray,         # [KV, N, bs, D]
-    v_pool: jnp.ndarray,         # [KV, N, bs, Dv]
+    k_pool: jnp.ndarray,         # [L, KV, N, bs, D] or [KV, N, bs, D]
+    v_pool: jnp.ndarray,         # [L, KV, N, bs, Dv] or [KV, N, bs, Dv]
     block_tables: jnp.ndarray,   # [B, nb] int32 (pad rows with a valid block)
     kv_len: jnp.ndarray,         # [B] int32 — valid entries incl. the query
+    layer=0,                     # int32 scalar — the stacked pools' layer
     *,
     softcap: Optional[float] = None,
     scale: Optional[float] = None,
@@ -390,9 +407,8 @@ def paged_decode_attention_pallas(
 ) -> jnp.ndarray:
     """Single-token paged decode: the query sits at position ``kv_len - 1``
     (its K/V already scattered), i.e. the T=1 window at base ``kv_len - 1``."""
-    group = q.shape[1] // k_pool.shape[0]
-    out = _paged_window_core(
-        q[:, None], k_pool, v_pool, _pad_tables(block_tables),
-        jnp.asarray(kv_len, jnp.int32) - 1, t_span=1, group=group,
-        softcap=softcap, scale=scale, interpret=interpret)
+    out = paged_window_attention_pallas(
+        q[:, None], k_pool, v_pool, block_tables,
+        jnp.asarray(kv_len, jnp.int32) - 1, layer, softcap=softcap,
+        scale=scale, interpret=interpret)
     return out[:, 0]

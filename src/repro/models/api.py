@@ -95,10 +95,12 @@ def init_paged_pools(cfg: ModelConfig, n_blocks: int, block_size: int,
                      dtype=jnp.float32, device=None):
     """Zero-initialized paged K/V pools mirroring the decode-cache tree:
     {"l{i}": {"mixer": {"k": [n_groups, KV, n_blocks, bs, hd], "v": ...}}} —
-    the stacked layer-group layout lax.scan consumes, with the per-sequence
-    (b, s) axes replaced by the physical (n_blocks, block_size) pool axes
-    shared by every sequence.  Head-major, so one (head, block) tile is a
-    contiguous [bs, hd] slab the paged kernel DMAs whole.  ``device``
+    the stacked layer-group layout, with the per-sequence (b, s) axes
+    replaced by the physical (n_blocks, block_size) pool axes shared by
+    every sequence.  The decode step carries each stacked pool through its
+    layer scan as one buffer, updated in place, and the paged kernel reads
+    it at the layer's index.  Head-major, so one (layer, head, block) tile
+    is a contiguous [bs, hd] slab the paged kernel DMAs whole.  ``device``
     commits the pools to that device (default: JAX's default device)."""
     ok, why = paged_compatible(cfg)
     if not ok:

@@ -352,22 +352,8 @@ def attn_decode(cfg: ModelConfig, spec: LayerSpec, p, x, cache, kv_len, *,
     return y.reshape(b, 1, -1), cache
 
 
-def attn_paged_decode(cfg: ModelConfig, spec: LayerSpec, p, x, pool,
-                      block_tables, kv_len, *,
-                      plan: Optional[ShardingPlan] = None):
-    """One-token decode against a *paged* KV pool.
-
-    x: [B, 1, d]; pool: {"k": [KV, N, bs, hd], "v": [KV, N, bs, dv]} — one
-    layer's head-major physical block pool; block_tables: [B, nb] int32
-    (rows padded with a valid null block); kv_len: [B] current lengths.  The
-    new token's K/V is scattered into slot ``kv_len`` of its sequence's block
-    table, then attention reads the cache through the table
-    (kernels.paged_attention).
-    Returns (y, updated pool).  MLA and sliding-window layers keep their
-    latent/ring cache paths — the serving runtime gates on api.paged_compatible.
-    Sharded decode (head-TP / sequence-sharded pools) is not implemented:
-    a plan carrying those axes is rejected rather than silently ignored.
-    """
+def _paged_gate(cfg: ModelConfig, spec: LayerSpec,
+                plan: Optional[ShardingPlan]):
     if cfg.mla is not None:
         raise NotImplementedError("paged decode: MLA uses the latent cache")
     if spec.attn == "window" and cfg.sliding_window:
@@ -375,55 +361,87 @@ def attn_paged_decode(cfg: ModelConfig, spec: LayerSpec, p, x, pool,
     if plan is not None and (plan.model_axis is not None or plan.seq_axes):
         raise NotImplementedError(
             "paged decode: model/seq-sharded plans are not supported yet")
+
+
+def _write_pages(pool, new, layer, blk, off):
+    """pool [L, KV, N, bs, d] <- new [R, KV, d]: row r at
+    ``(layer, :, blk[r], off[r], :)``, one ``dynamic_update_slice`` a row,
+    in place on the carried pool.  A scatter (``.at[].set``) here makes XLA
+    relayout the whole stacked pool around it on every layer."""
+    new = new.astype(pool.dtype)
+    zero = jnp.zeros((), jnp.int32)
+    for r in range(new.shape[0]):
+        pool = jax.lax.dynamic_update_slice(
+            pool, new[r][None, :, None, None, :],
+            (layer, zero, blk[r], off[r], zero))
+    return pool
+
+
+def attn_paged_decode(cfg: ModelConfig, spec: LayerSpec, p, x, pool,
+                      block_tables, kv_len, layer, *,
+                      plan: Optional[ShardingPlan] = None):
+    """One-token decode against a *paged* KV pool.
+
+    x: [B, 1, d]; pool: {"k": [L, KV, N, bs, hd], "v": [L, KV, N, bs, dv]}
+    — the layer-stacked head-major physical block pools, of which this
+    layer is ``layer`` (int32 scalar); block_tables: [B, nb] int32 (rows
+    padded with a valid null block); kv_len: [B] current lengths.  The new
+    token's K/V is written into slot ``kv_len`` of its sequence's block
+    table in place, then attention reads layer ``layer`` through the table
+    (kernels.paged_attention).
+    Returns (y, updated pools).  MLA and sliding-window layers keep their
+    latent/ring cache paths — the serving runtime gates on api.paged_compatible.
+    Sharded decode (head-TP / sequence-sharded pools) is not implemented:
+    a plan carrying those axes is rejected rather than silently ignored.
+    """
+    _paged_gate(cfg, spec, plan)
     b = x.shape[0]
     positions = kv_len[:, None]
     if cfg.rope == "mrope":
         positions = jnp.broadcast_to(positions, (3, b, 1))
     q, k, v = _qkv(cfg, p, x, positions)
-    bs = pool["k"].shape[2]
+    bs = pool["k"].shape[3]
     blk = block_tables[jnp.arange(b), kv_len // bs]          # [B] physical ids
     off = kv_len % bs
     with device_scope("kv_write"):
-        k_pool = pool["k"].at[:, blk, off].set(jnp.swapaxes(k[:, 0], 0, 1))
-        v_pool = pool["v"].at[:, blk, off].set(jnp.swapaxes(v[:, 0], 0, 1))
+        k_pool = _write_pages(pool["k"], k[:, 0], layer, blk, off)
+        v_pool = _write_pages(pool["v"], v[:, 0], layer, blk, off)
     out = paged_decode_attention(q[:, 0], k_pool, v_pool, block_tables,
-                                 kv_len + 1, softcap=cfg.attn_softcap)
+                                 kv_len + 1, layer, softcap=cfg.attn_softcap)
     y = apply_dense(p["o"], out.reshape(b, -1))
     return y.reshape(b, 1, -1), {"k": k_pool, "v": v_pool}
 
 
 def attn_paged_spec(cfg: ModelConfig, spec: LayerSpec, p, x, pool,
-                    block_tables, kv_len, blk, off, *,
+                    block_tables, kv_len, layer, blk, off, *,
                     plan: Optional[ShardingPlan] = None):
     """Multi-token decode (speculative verification) against a paged pool.
 
     x: [B, T, d] — the current input token plus T-1 draft tokens per
-    sequence; kv_len: [B] history length *before* the window; blk/off:
-    [B, T] int32 scatter targets for each window position's K/V, computed
-    host-side by the engine from its block tables (invalid positions point
-    at the null block, so a slot mid-prefill or past its budget never
-    clobbers live blocks).  All T positions' K/V are scattered in one
-    batched write, then attention reads through the table with causal
-    masking of the window (kernels.paged_attention.paged_window_attention).
-    Returns (y [B, T, d], updated pool).  Same architecture gates as
+    sequence; pool and ``layer`` as in ``attn_paged_decode``; kv_len: [B]
+    history length *before* the window; blk/off: [B, T] int32 write targets
+    for each window position's K/V, computed host-side by the engine from
+    its block tables (invalid positions point at the null block, so a slot
+    mid-prefill or past its budget never clobbers live blocks).  Each of
+    the B·T positions' K/V is written in place, then attention reads
+    through the table with causal masking of the window
+    (kernels.paged_attention.paged_window_attention).
+    Returns (y [B, T, d], updated pools).  Same architecture gates as
     ``attn_paged_decode``."""
-    if cfg.mla is not None:
-        raise NotImplementedError("paged decode: MLA uses the latent cache")
-    if spec.attn == "window" and cfg.sliding_window:
-        raise NotImplementedError("paged decode: window layers use ring cache")
-    if plan is not None and (plan.model_axis is not None or plan.seq_axes):
-        raise NotImplementedError(
-            "paged decode: model/seq-sharded plans are not supported yet")
+    _paged_gate(cfg, spec, plan)
     b, t, _ = x.shape
     positions = kv_len[:, None] + jnp.arange(t)[None, :]
     if cfg.rope == "mrope":
         positions = jnp.broadcast_to(positions, (3, b, t))
     q, k, v = _qkv(cfg, p, x, positions)
+    blk, off = blk.reshape(-1), off.reshape(-1)
     with device_scope("kv_write"):
-        k_pool = pool["k"].at[:, blk, off].set(jnp.moveaxis(k, 2, 0))
-        v_pool = pool["v"].at[:, blk, off].set(jnp.moveaxis(v, 2, 0))
+        k_pool = _write_pages(pool["k"], k.reshape(b * t, *k.shape[2:]),
+                              layer, blk, off)
+        v_pool = _write_pages(pool["v"], v.reshape(b * t, *v.shape[2:]),
+                              layer, blk, off)
     out = paged_window_attention(q, k_pool, v_pool, block_tables, kv_len,
-                                 softcap=cfg.attn_softcap)
+                                 layer, softcap=cfg.attn_softcap)
     y = apply_dense(p["o"], out.reshape(b, t, -1))
     return y, {"k": k_pool, "v": v_pool}
 

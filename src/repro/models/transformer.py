@@ -73,12 +73,13 @@ def block_init(cfg: ModelConfig, spec: LayerSpec, key, dtype):
 
 def block_apply(cfg: ModelConfig, spec: LayerSpec, p, x, *, positions, plan,
                 cache, kv_len, mode: str, cache_len: int, block_tables=None,
-                spec_scatter=None):
+                spec_scatter=None, layer=None):
     """Returns (x, new_cache_entry, aux).  When ``block_tables`` is given the
-    decode path reads/writes the paged KV pool instead of a contiguous cache
-    (attention layers only; gated by api.paged_compatible).  ``spec_scatter``
-    ((blk, off) [B, T] target arrays) switches the paged decode to the
-    multi-token speculative-verification window."""
+    decode path reads/writes the layer-stacked paged KV pool at group
+    ``layer`` instead of a contiguous cache (attention layers only; gated by
+    api.paged_compatible), and returns the whole updated pool.
+    ``spec_scatter`` ((blk, off) [B, T] target arrays) switches the paged
+    decode to the multi-token speculative-verification window."""
     aux = {}
     h = apply_norm(cfg, p["norm1"], x)
     new_cache = {}
@@ -91,11 +92,12 @@ def block_apply(cfg: ModelConfig, spec: LayerSpec, p, x, *, positions, plan,
                     and spec_scatter is not None:
                 mx, c = attn.attn_paged_spec(cfg, spec, p["mixer"], h,
                                              cache["mixer"], block_tables,
-                                             kv_len, *spec_scatter, plan=plan)
+                                             kv_len, layer, *spec_scatter,
+                                             plan=plan)
             elif mode == "decode" and block_tables is not None:
                 mx, c = attn.attn_paged_decode(cfg, spec, p["mixer"], h,
                                                cache["mixer"], block_tables,
-                                               kv_len, plan=plan)
+                                               kv_len, layer, plan=plan)
             elif mode == "decode":
                 mx, c = attn.attn_decode(cfg, spec, p["mixer"], h,
                                          cache["mixer"], kv_len, plan=plan)
@@ -195,32 +197,47 @@ def init_params(cfg: ModelConfig, key, dtype=None):
 def apply_stack(cfg: ModelConfig, params, x, *, positions, plan, mode: str,
                 cache=None, kv_len=None, cache_len: int = 0,
                 block_tables=None, spec_scatter=None):
-    """Run all layer groups.  Returns (x, new_cache, aux)."""
+    """Run all layer groups.  Returns (x, new_cache, aux).
+
+    Paged decode (``block_tables`` given) carries the stacked pools through
+    the scan and scans over the group index instead: each layer updates the
+    one pool buffer in place and the paged kernel reads it at its layer, so
+    no layer's pool is sliced out and restacked.  Every other mode scans
+    the cache along with the weights."""
     period = group_period(cfg)
     specs = cfg.layer_plan()[:period]
+    paged = block_tables is not None
 
     def body(carry, xs):
-        xc, aux_sum = carry
+        xc, aux_sum, pools = carry
         gp, gc = xs
+        layer = None
+        if paged:                 # xs holds the group index, not its cache
+            layer, gc = gc, pools
         new_gc = {}
         for i in range(period):
             c_i = gc[f"l{i}"] if gc is not None else None
             xc, nc, aux = block_apply(
                 cfg, specs[i], gp[f"l{i}"], xc, positions=positions, plan=plan,
                 cache=c_i, kv_len=kv_len, mode=mode, cache_len=cache_len,
-                block_tables=block_tables, spec_scatter=spec_scatter)
+                block_tables=block_tables, spec_scatter=spec_scatter,
+                layer=layer)
             if nc is not None:
                 new_gc[f"l{i}"] = nc
             if "lb_loss" in aux:
                 aux_sum = aux_sum + aux["lb_loss"]
-        return (xc, aux_sum), (new_gc if new_gc else None)
+        if paged:
+            return (xc, aux_sum, new_gc), None
+        return (xc, aux_sum, None), (new_gc if new_gc else None)
 
     if plan is not None and plan.remat and mode == "train":
         body = jax.checkpoint(body)
 
-    xs = (params["blocks"], cache)
-    (x, aux_sum), new_cache = lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
-    return x, new_cache, {"lb_loss": aux_sum}
+    groups = jnp.arange(cfg.n_layers // period, dtype=jnp.int32)
+    carry = (x, jnp.zeros((), jnp.float32), cache if paged else None)
+    xs = (params["blocks"], groups if paged else cache)
+    (x, aux_sum, pools), new_cache = lax.scan(body, carry, xs)
+    return x, (pools if paged else new_cache), {"lb_loss": aux_sum}
 
 
 # ----------------------------------------------------------------- LM heads

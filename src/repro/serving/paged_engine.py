@@ -340,8 +340,8 @@ class PagedEngine:
         self._chunk = 0 if pcfg.chunk_tokens <= 0 \
             else -(-pcfg.chunk_tokens // bs) * bs
         # donate the pools (argnum 2 of (params, tokens, pools, bt, kv_len))
-        # so the per-step K/V scatter aliases in place instead of copying the
-        # whole pool every token
+        # so the step's in-place K/V writes land in the caller's buffers
+        # instead of a copy of the whole pool every token
         self._decode = jax.jit(
             functools.partial(api.paged_decode_step, cfg, plan=plan),
             donate_argnums=(2,))

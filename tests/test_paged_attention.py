@@ -4,7 +4,9 @@ padded (null-block) table tails; multi-token window parity (speculative
 verification) and the power-of-two block-table bucketing that caps jit
 specialization churn.  Head dims below 128 take the page walk; the
 live-page sweep (head dim 128) is checked on bf16 pools at the edges of a
-compute block, and against NaN-filled dead blocks."""
+compute block, and against NaN-filled dead blocks.  Cases that give a
+stack of layers read one layer of a ``[L, KV, N, bs, D]`` pool whose other
+layers are NaN, as the model step hands the kernel its pool."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -19,17 +21,37 @@ from repro.kernels.paged_attention.ref import (
 from repro.kernels.paged_attention.xla import (paged_decode_attention_xla,
                                                paged_window_attention_xla)
 
-# (b, h, kv, d, block_size, logical_blocks, n_phys_blocks, softcap)
+# (b, h, kv, d, block_size, logical_blocks, n_phys_blocks, softcap
+#  [, (n_layers, layer)] -- a stacked pool read at that layer)
 CASES = [
     (2, 4, 2, 16, 8, 4, 16, None),
     (3, 6, 3, 8, 16, 3, 24, 50.0),
     (1, 8, 8, 32, 4, 6, 32, None),
     (4, 16, 2, 64, 16, 2, 48, None),
+    # head dim 64 walks the pages, 128 sweeps them: first, middle, last
+    (4, 16, 2, 64, 16, 2, 48, None, (3, 0)),
+    (2, 12, 2, 128, 8, 20, 48, None, (3, 0)),
+    (4, 16, 2, 64, 16, 2, 48, 50.0, (3, 1)),
+    (2, 12, 2, 128, 8, 20, 48, 50.0, (3, 1)),
+    (4, 16, 2, 64, 16, 2, 48, None, (3, 2)),
+    (2, 12, 2, 128, 8, 20, 48, None, (3, 2)),
 ]
 
 
+def _stack(pool, stack):
+    """Pool for a case: ``pool`` itself and layer 0, or ``pool`` as layer
+    ``layer`` of ``n_layers`` whose other layers are NaN."""
+    if not stack:
+        return pool, 0
+    n_layers, layer = stack
+    out = np.full((n_layers,) + pool.shape, np.nan, pool.dtype)
+    out[layer] = pool
+    return out, layer
+
+
 def _mk(rng, case):
-    b, h, kv, d, bs, nb, n, cap = case
+    b, h, kv, d, bs, nb, n, cap, *stack = case
+    stack = stack[0] if stack else None
     q = rng.standard_normal((b, h, d)).astype(np.float32)
     kp = rng.standard_normal((kv, n, bs, d)).astype(np.float32)
     vp = rng.standard_normal((kv, n, bs, d)).astype(np.float32)
@@ -38,22 +60,24 @@ def _mk(rng, case):
     ref = decode_attention_reference(
         q, gather_pool(jnp.asarray(kp), jnp.asarray(bt)),
         gather_pool(jnp.asarray(vp), jnp.asarray(bt)), kv_len, softcap=cap)
-    return q, kp, vp, bt, kv_len, cap, ref
+    (kp, layer), (vp, _) = (_stack(p, stack) for p in (kp, vp))
+    return q, kp, vp, bt, kv_len, layer, cap, ref
 
 
 @pytest.mark.parametrize("case", CASES)
 @pytest.mark.parametrize("impl", ["ref", "xla", "pallas"])
 def test_paged_matches_contiguous_oracle(rng, case, impl):
-    q, kp, vp, bt, kv_len, cap, ref = _mk(rng, case)
+    q, kp, vp, bt, kv_len, layer, cap, ref = _mk(rng, case)
     if impl == "ref":
-        out = paged_decode_attention_reference(q, kp, vp, bt, kv_len,
+        out = paged_decode_attention_reference(q, kp, vp, bt, kv_len, layer,
                                                softcap=cap)
     elif impl == "xla":
-        out = paged_decode_attention_xla(q, kp, vp, bt, kv_len, softcap=cap)
+        out = paged_decode_attention_xla(q, kp, vp, bt, kv_len, layer,
+                                         softcap=cap)
     else:
         out = paged_decode_attention_pallas(
-            q, kp, vp, jnp.asarray(bt), jnp.asarray(kv_len), softcap=cap,
-            interpret=True)
+            q, kp, vp, jnp.asarray(bt), jnp.asarray(kv_len), layer,
+            softcap=cap, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
@@ -84,24 +108,33 @@ def test_padded_table_tail_is_inert(rng, impl):
 
 # ------------------------------------------------- multi-token window kernel
 
-# (b, h, kv, d, block_size, logical_blocks, n_phys_blocks, softcap)
+# (b, h, kv, d, block_size, logical_blocks, n_phys_blocks, softcap
+#  [, (n_layers, layer)])
 WINDOW_CASES = [
     (2, 4, 2, 16, 8, 4, 16, None),       # group 2: the T fold packs rows
     (3, 6, 3, 8, 16, 3, 24, 50.0),       # softcap + group 2 over 3 kv heads
     (1, 8, 8, 32, 4, 6, 32, None),       # MHA (group 1)
     (2, 16, 2, 64, 16, 2, 48, None),     # wide GQA group 8
+    # stacked pools: the sweep at the first layer and the middle one, the
+    # page walk at the last
+    (2, 12, 2, 128, 8, 20, 48, None, (3, 0)),
+    (2, 12, 2, 128, 8, 20, 48, 50.0, (3, 1)),
+    (2, 16, 2, 64, 16, 2, 48, None, (3, 2)),
 ]
 
 
 def _mk_window(rng, case, t):
-    b, h, kv, d, bs, nb, n, cap = case
+    b, h, kv, d, bs, nb, n, cap, *stack = case
+    stack = stack[0] if stack else None
     q = rng.standard_normal((b, t, h, d)).astype(np.float32)
     kp = rng.standard_normal((kv, n, bs, d)).astype(np.float32)
     vp = rng.standard_normal((kv, n, bs, d)).astype(np.float32)
     bt = rng.permutation(n)[:b * nb].reshape(b, nb).astype(np.int32)
     # ragged histories: every sequence a different base length, window fits
     base = rng.integers(0, nb * bs - t + 1, size=b).astype(np.int32)
-    return q, kp, vp, bt, base, cap
+    ref = paged_window_attention_reference(q, kp, vp, bt, base, softcap=cap)
+    (kp, layer), (vp, _) = (_stack(p, stack) for p in (kp, vp))
+    return q, kp, vp, bt, base, layer, cap, ref
 
 
 @pytest.mark.parametrize("case", WINDOW_CASES)
@@ -110,15 +143,15 @@ def _mk_window(rng, case, t):
 def test_window_matches_reference(rng, case, t, impl):
     """[B, T, H, D] verify window: causal against the paged history and the
     window itself, for ragged kv_len and GQA groups."""
-    q, kp, vp, bt, base, cap = _mk_window(rng, case, t)
-    ref = paged_window_attention_reference(q, kp, vp, bt, base, softcap=cap)
+    q, kp, vp, bt, base, layer, cap, ref = _mk_window(rng, case, t)
     if impl == "xla":
         out = paged_window_attention_xla(q, kp, vp, jnp.asarray(bt),
-                                         jnp.asarray(base), softcap=cap)
+                                         jnp.asarray(base), layer,
+                                         softcap=cap)
     else:
         out = paged_window_attention_pallas(
-            q, kp, vp, jnp.asarray(bt), jnp.asarray(base), softcap=cap,
-            interpret=True)
+            q, kp, vp, jnp.asarray(bt), jnp.asarray(base), layer,
+            softcap=cap, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=1e-5, rtol=2e-5)
 
@@ -127,17 +160,19 @@ def test_window_matches_reference(rng, case, t, impl):
 def test_window_t1_reproduces_single_token_kernel(rng, case):
     """T=1 at base kv_len-1 must be *exactly* the single-token paged decode
     kernel — same core, same row layout, bitwise."""
-    b, h, kv, d, bs, nb, n, cap = case
+    b, h, kv, d, bs, nb, n, cap, *stack = case
     q = rng.standard_normal((b, h, d)).astype(np.float32)
     kp = rng.standard_normal((kv, n, bs, d)).astype(np.float32)
     vp = rng.standard_normal((kv, n, bs, d)).astype(np.float32)
+    (kp, layer), (vp, _) = (_stack(p, stack[0] if stack else None)
+                            for p in (kp, vp))
     bt = rng.permutation(n)[:b * nb].reshape(b, nb).astype(np.int32)
     kv_len = rng.integers(1, nb * bs + 1, size=b).astype(np.int32)
     single = paged_decode_attention_pallas(
-        q, kp, vp, jnp.asarray(bt), jnp.asarray(kv_len), softcap=cap,
+        q, kp, vp, jnp.asarray(bt), jnp.asarray(kv_len), layer, softcap=cap,
         interpret=True)
     window = paged_window_attention_pallas(
-        q[:, None], kp, vp, jnp.asarray(bt), jnp.asarray(kv_len) - 1,
+        q[:, None], kp, vp, jnp.asarray(bt), jnp.asarray(kv_len) - 1, layer,
         softcap=cap, interpret=True)[:, 0]
     np.testing.assert_array_equal(np.asarray(single), np.asarray(window))
 

@@ -214,3 +214,99 @@ def test_paged_kv_cache_batched_append_matches_per_token(rng):
     np.testing.assert_allclose(np.asarray(vb), np.asarray(vl))
     np.testing.assert_allclose(np.asarray(kb), k_all)
     np.testing.assert_allclose(np.asarray(vb), v_all)
+
+
+# --------------------------------------- the decode step updates pools in place
+
+def _stacked_step_case(rng, t):
+    """A 3-layer qwen2-shaped model at head dim 128 (the kernel's live-page
+    sweep), random pools, and four rows: two live, one a free slot whose
+    table is all null block 0, and, for a window (t > 1), one whose last
+    position points at the null block as the engine's past-budget ones do.
+    Returns (cfg, params, tokens, pools, tables, kv_len, blk, off)."""
+    from dataclasses import replace
+    cfg = replace(get_config("qwen2-1.5b").reduced(n_layers=3),
+                  head_dim=128, n_kv_heads=2)
+    params = api.init_params(cfg, jax.random.PRNGKey(1), jnp.float32)
+    n_blocks, nb = 16, 4
+    pools = api.init_paged_pools(cfg, n_blocks, BS, jnp.float32)
+    pools = jax.tree.map(lambda p: jnp.asarray(
+        rng.standard_normal(p.shape).astype(np.float32)), pools)
+    tables = np.zeros((4, nb), np.int32)
+    tables[0, :2] = [3, 7]
+    tables[1, :3] = [5, 1, 12]
+    tables[3, :1] = [9]
+    kv_len = np.array([BS + 2, 2 * BS - 2, 0, 3], np.int32)
+    pos = kv_len[:, None] + np.arange(t)[None, :]
+    blk = tables[np.arange(4)[:, None], pos // BS]
+    off = (pos % BS).astype(np.int32)
+    blk[2], off[2] = 0, 0                       # the free slot
+    if t > 1:
+        blk[3, -1], off[3, -1] = 0, 0           # past the row's budget
+    tokens = rng.integers(0, cfg.vocab_size, (4, t)).astype(np.int32)
+    return cfg, params, tokens, pools, tables, kv_len, blk, off
+
+
+def _per_layer_step(cfg, params, tokens, pools, tables, kv_len,
+                    spec_scatter):
+    """The decode step with each layer's pool sliced out of the stack,
+    updated on its own and written back, layer by layer: the per-layer
+    dataflow that the scan's carried pool replaces."""
+    from repro.models import transformer as T
+    from repro.models.common import apply_norm
+    period = T.group_period(cfg)
+    specs = cfg.layer_plan()[:period]
+    x = T.embed_tokens(cfg, params, tokens)
+    for g in range(cfg.n_layers // period):
+        gp = jax.tree.map(lambda w: w[g], params["blocks"])
+        for i in range(period):
+            one = jax.tree.map(lambda p: p[g:g + 1], pools[f"l{i}"])
+            x, nc, _ = T.block_apply(
+                cfg, specs[i], gp[f"l{i}"], x, positions=None, plan=None,
+                cache=one, kv_len=kv_len, mode="decode", cache_len=0,
+                block_tables=tables, spec_scatter=spec_scatter,
+                layer=jnp.int32(0))
+            pools = {**pools, f"l{i}": jax.tree.map(
+                lambda p, n: p.at[g].set(n[0]), pools[f"l{i}"], nc)}
+    x = apply_norm(cfg, params["final_norm"], x)
+    return T.lm_head(cfg, params, x if spec_scatter else x[:, 0]), pools
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas_interpret"])
+@pytest.mark.parametrize("t", [1, 3])
+def test_step_writes_only_each_rows_slot(rng, t, backend):
+    """One ``paged_decode_step`` (t = 1) or ``paged_spec_step`` (t = 3):
+    the pools change only at ``[g, :, blk, off, :]`` of each live row's
+    positions, in every group g; the free slot and the past-budget position
+    write only the null block; logits and pools equal the per-layer
+    dataflow's on the same inputs."""
+    from repro.kernels.backend import use_backend
+    cfg, params, tokens, pools, tables, kv_len, blk, off = \
+        _stacked_step_case(rng, t)
+    args = (jnp.asarray(tokens), pools, jnp.asarray(tables),
+            jnp.asarray(kv_len))
+    spec_scatter = None
+    with use_backend(backend):
+        if t == 1:
+            logits, new = api.paged_decode_step(cfg, params, *args)
+        else:
+            spec_scatter = (jnp.asarray(blk), jnp.asarray(off))
+            logits, new = api.paged_spec_step(cfg, params, *args,
+                                              *spec_scatter)
+        ref_logits, ref_pools = _per_layer_step(
+            cfg, params, *args, spec_scatter)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(ref_logits),
+                               atol=1e-5, rtol=1e-5)
+    live = {(int(b_), int(o_)) for b_, o_ in zip(blk.ravel(), off.ravel())
+            if b_ != 0}
+    for old, got, ref in zip(jax.tree.leaves(pools), jax.tree.leaves(new),
+                             jax.tree.leaves(ref_pools)):
+        old, got = np.asarray(old), np.asarray(got)
+        np.testing.assert_allclose(got, np.asarray(ref), atol=1e-5,
+                                   rtol=1e-5)
+        changed = np.any(got != old, axis=(1, 4))          # [G, N, bs]
+        for g in range(changed.shape[0]):
+            where = {(int(b_), int(o_))
+                     for b_, o_ in np.argwhere(changed[g])}
+            assert live <= where, (g, live - where)
+            assert {w for w in where if w[0] != 0} == live, g

@@ -4,7 +4,9 @@ at chatglm2-6b's, and whole jitted paged-decode and prefill steps of
 qwen2-1.5b, and chatglm2-6b's paged-decode step.  Nothing runs — the TPU's
 compiler refuses what the chip would refuse (block shapes it cannot tile,
 too much fast memory, a program larger than the device), which interpret
-mode never shows.
+mode never shows.  The decode steps must also update the layer-stacked
+pool in place: no copy of it, or of one layer of it, and no second pool
+held as a temporary.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU's library, and every test
@@ -12,6 +14,7 @@ worker imports this file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +34,7 @@ from repro.models import api
 
 V5E_HBM_BYTES = 16 * 2**30
 B, S, BS, N_BLOCKS, NB = 4, 512, 8, 1025, 256
+N_LAYERS = 3          # the paged kernels read the middle of a stacked pool
 
 
 @pytest.fixture(scope="module")
@@ -77,8 +81,8 @@ def _kernel_call(kernel, spec, h, kv, d, dtype):
                                          spec((B, 4 * S, kv, d), dtype),
                                          spec((B, 4 * S, kv, d), dtype),
                                          spec((B,), i32))
-    pool = spec((kv, N_BLOCKS, BS, d), dtype)
-    table = (spec((B, NB), i32), spec((B,), i32))
+    pool = spec((N_LAYERS, kv, N_BLOCKS, BS, d), dtype)
+    table = (spec((B, NB), i32), spec((B,), i32), spec((), i32))  # + layer
     if kernel == "paged_decode":
         return paged_decode_attention_pallas, (spec((B, h, d), dtype), pool,
                                                pool, *table)
@@ -130,38 +134,77 @@ def _fits_one_chip(compiled) -> bool:
     return need < V5E_HBM_BYTES
 
 
-def test_paged_decode_step_compiles_for_v5e(spec):
-    """The served qwen2-1.5b decode step, float32, at full width: the
-    Pallas paged kernel is in the program and the program fits one chip."""
-    cfg = get_config("qwen2-1.5b")
-    params, pools = _params_and_pools(spec, cfg)
+def _compile_decode_step(spec, cfg, dtype):
+    params, pools = _params_and_pools(spec, cfg, dtype)
     step = jax.jit(functools.partial(api.paged_decode_step, cfg),
                    donate_argnums=(2,))
     with use_backend("pallas"):
         compiled = step.lower(params, spec((B, 1), jnp.int32), pools,
                               spec((B, NB), jnp.int32),
                               spec((B,), jnp.int32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    return compiled, pools
+
+
+def _pool_copies(text, pools) -> list:
+    """Lines of the compiled HLO that copy the stacked pool or one layer of
+    it (a ``copy``, or a fusion whose name starts with ``copy``)."""
+    shapes = set()
+    for leaf in jax.tree.leaves(pools):
+        shapes.add(",".join(map(str, leaf.shape)))
+        shapes.add(",".join(map(str, leaf.shape[1:])))
+    dims = "|".join(re.escape(s) for s in shapes)
+    pat = re.compile(r"%%copy[\w.-]* = \w+\[(%s)\]\{" % dims)
+    return [line for line in text.splitlines() if pat.search(line)]
+
+
+def _pool_bytes(pools) -> int:
+    return sum(x.size * jnp.dtype(x.dtype).itemsize
+               for x in jax.tree.leaves(pools))
+
+
+def test_paged_decode_step_compiles_for_v5e(spec):
+    """The served qwen2-1.5b decode step, float32, at full width: the
+    Pallas paged kernel is in the program, the program fits one chip, and
+    the stacked pool is never copied whole or a layer at a time.  (Its
+    temporaries are the float32 weights' bfloat16 casts, hoisted out of the
+    layer scan by the compiler, not a pool: the bfloat16 step checks that.)"""
+    compiled, pools = _compile_decode_step(spec, get_config("qwen2-1.5b"),
+                                           jnp.float32)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
     assert _fits_one_chip(compiled)
+    assert _pool_copies(text, pools) == []
+
+
+def _assert_pool_in_place(compiled, pools):
+    """Every layer writes its new K/V into the one carried pool and the
+    kernel reads it by layer: no second pool held as a temporary (under an
+    eighth of the pools' bytes) and no copy of a pool or of one layer."""
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < _pool_bytes(pools) / 8, (temp, _pool_bytes(pools))
+    assert _pool_copies(compiled.as_text(), pools) == []
+
+
+def test_bf16_decode_step_updates_the_pool_in_place(spec):
+    """qwen2-1.5b's decode step as its benchmark cell serves it, bfloat16:
+    the pool is updated in place."""
+    compiled, pools = _compile_decode_step(spec, get_config("qwen2-1.5b"),
+                                           jnp.bfloat16)
+    _assert_pool_in_place(compiled, pools)
 
 
 def test_chatglm2_decode_step_compiles_for_v5e(spec):
     """The served chatglm2-6b decode step at full width in bfloat16, as its
     benchmark cell serves it: the paged kernel is in, the half-head rotary
-    in pairs is in the ``attention/rope`` scope, and 12.5 GB of weights
-    with the pool fit one chip."""
-    cfg = get_config("chatglm2-6b")
-    params, pools = _params_and_pools(spec, cfg, jnp.bfloat16)
-    step = jax.jit(functools.partial(api.paged_decode_step, cfg),
-                   donate_argnums=(2,))
-    with use_backend("pallas"):
-        compiled = step.lower(params, spec((B, 1), jnp.int32), pools,
-                              spec((B, NB), jnp.int32),
-                              spec((B,), jnp.int32)).compile()
+    in pairs is in the ``attention/rope`` scope, 12.5 GB of weights with
+    the pool fit one chip, and the pool is updated in place."""
+    compiled, pools = _compile_decode_step(spec, get_config("chatglm2-6b"),
+                                           jnp.bfloat16)
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "attention/rope/" in text
     assert _fits_one_chip(compiled)
+    _assert_pool_in_place(compiled, pools)
 
 
 def test_prefill_step_compiles_for_v5e(spec):
