@@ -1,5 +1,5 @@
 """Chip smoke: serve qwen2-1.5b at its published widths through
-``serve.py --paged`` on a TPU, and check what came out.
+``serve.py`` on a TPU, and check what came out.
 
   python chip_smoke.py             # one chip
   python chip_smoke.py --chips 4   # four replicas on four chips vs one replica
@@ -93,7 +93,7 @@ def kv_budget(cfg) -> int:
 
 
 def serve_argv(cfg, *extra: str) -> list:
-    return ["--paged", "--no-reduced", "--arch", ARCH,
+    return ["--no-reduced", "--arch", ARCH,
             "--max-new", str(MAX_NEW), "--kv-budget", str(kv_budget(cfg)),
             *extra]
 

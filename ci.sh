@@ -21,10 +21,9 @@ interleave_smoke() {
     python - <<'PY'
 import copy, jax, jax.numpy as jnp
 from repro.configs import get_config
-from repro.core.types import Batch, Request
+from repro.core.types import Request
 from repro.models import api
-from repro.serving import (EngineConfig, InferenceEngine, PagedEngine,
-                           PagedEngineConfig)
+from repro.serving import PagedEngine, PagedEngineConfig
 
 cfg = get_config("smollm-135m").reduced()
 params = api.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
@@ -34,11 +33,10 @@ reqs = [Request(rid=0, tokens=[3] * 16, input_len=16, slo=1000.0,
                 arrival=0.0, true_output_len=6),
         Request(rid=1, tokens=[5] * 8, input_len=8, slo=0.001,
                 arrival=0.0, true_output_len=4)]
-ref = InferenceEngine(cfg, params,
-                      EngineConfig(max_batch=2, cache_len=32,
-                                   max_new_tokens=8)).run_batch(
-    Batch(requests=[copy.copy(r) for r in reqs]),
-    true_lens={r.rid: r.true_output_len for r in reqs})
+# reference: a plain paged run with room for both, whole-prompt prefill
+ref = PagedEngine(cfg, params, PagedEngineConfig(
+    max_batch=2, block_size=8, n_blocks=24, max_seq_len=32,
+    max_new_tokens=8)).run_continuous([copy.copy(r) for r in reqs])
 eng = PagedEngine(cfg, params, PagedEngineConfig(
     max_batch=2, block_size=8, n_blocks=5, max_seq_len=32,
     max_new_tokens=8, chunk_tokens=8, preempt=True))
@@ -56,10 +54,9 @@ spec_smoke() {
     python - <<'PY'
 import copy, jax, jax.numpy as jnp
 from repro.configs import get_config
-from repro.core.types import Batch, Request
+from repro.core.types import Request
 from repro.models import api
-from repro.serving import (EngineConfig, InferenceEngine, PagedEngine,
-                           PagedEngineConfig)
+from repro.serving import PagedEngine, PagedEngineConfig
 
 cfg = get_config("smollm-135m").reduced()
 params = api.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
@@ -68,11 +65,10 @@ params = api.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
 reqs = [Request(rid=i, tokens=([7 + i, 11, 13 + i, 17] * 6)[:20],
                 input_len=20, slo=60.0, arrival=0.0, true_output_len=10)
         for i in range(4)]
-ref = InferenceEngine(cfg, params,
-                      EngineConfig(max_batch=4, cache_len=48,
-                                   max_new_tokens=12)).run_batch(
-    Batch(requests=[copy.copy(r) for r in reqs]),
-    true_lens={r.rid: r.true_output_len for r in reqs})
+# reference: the same engine shape without speculation
+ref = PagedEngine(cfg, params, PagedEngineConfig(
+    max_batch=2, block_size=8, n_blocks=24, max_seq_len=48,
+    max_new_tokens=12)).run_continuous([copy.copy(r) for r in reqs])
 eng = PagedEngine(cfg, params, PagedEngineConfig(
     max_batch=2, block_size=8, n_blocks=24, max_seq_len=48,
     max_new_tokens=12, spec_tokens=4))
@@ -178,7 +174,7 @@ PY
 
 fleet_smoke() {
     echo "== fleet smoke (2 models x 2 tiers, model-aware routing, v6 metrics) =="
-    python -m repro.launch.serve --reduced --arch chatglm2-6b \
+    python -m repro.launch.simulate --reduced --arch chatglm2-6b \
         --models "chatglm2-6b:0.6,qwen2-1.5b:0.4" --requests 32 \
         --replicas 2 --router slo_aware --fleet joint \
         --metrics-json /tmp/fleet_m.json > /dev/null
@@ -204,8 +200,8 @@ PY
 }
 
 traced_smoke() {
-    echo "== traced smoke (serve.py --paged --trace/--metrics-json) =="
-    python -m repro.launch.serve --reduced --paged --preempt --speculate \
+    echo "== traced smoke (serve.py --trace/--metrics-json) =="
+    python -m repro.launch.serve --reduced --preempt --speculate \
         --chunk-tokens 8 --requests 8 \
         --trace /tmp/trace.json --metrics-json /tmp/m.json > /dev/null
     python - <<'PY'
@@ -232,9 +228,9 @@ PY
 
 profile_smoke() {
     echo "== profile smoke (--profile-out / --profile-in round trip) =="
-    python -m repro.launch.serve --reduced --paged --speculate \
+    python -m repro.launch.serve --reduced --speculate \
         --chunk-tokens 8 --requests 8 --profile-out /tmp/prof.json > /tmp/serve_a.log
-    python -m repro.launch.serve --reduced --paged --speculate \
+    python -m repro.launch.serve --reduced --speculate \
         --chunk-tokens 8 --requests 8 --profile-in /tmp/prof.json > /tmp/serve_b.log
     da=$(grep -o 'outputs_digest=[0-9a-f]*' /tmp/serve_a.log)
     db=$(grep -o 'outputs_digest=[0-9a-f]*' /tmp/serve_b.log)

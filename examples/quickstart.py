@@ -4,7 +4,7 @@
 2. train the resource profiler's length predictor,
 3. schedule with SLO-ODBS,
 4. plan a deployment with HELR,
-5. execute one batch on a real (reduced) JAX model.
+5. execute the batches on a real (reduced) JAX model, paged engine.
 
 Run: PYTHONPATH=src python examples/quickstart.py
 """
@@ -18,7 +18,7 @@ from repro.core.profiler import PredictorConfig
 from repro.core.types import DeviceNode
 from repro.data.workload import WorkloadConfig, gen_requests, train_pairs
 from repro.models import api
-from repro.serving import EngineConfig, InferenceEngine
+from repro.serving import PagedEngine, PagedEngineConfig
 
 # --- 1. workload -----------------------------------------------------------
 cfg = get_config("smollm-135m").reduced()
@@ -49,12 +49,11 @@ print(f"HELR device map: path={dmap.path} layers={dmap.layers}")
 
 # --- 5. execute on the real model ------------------------------------------
 params = api.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
-engine = InferenceEngine(cfg, params, EngineConfig(max_batch=4, cache_len=32,
-                                                   max_new_tokens=8))
+engine = PagedEngine(cfg, params, PagedEngineConfig(
+    max_batch=4, block_size=8, n_blocks=32, max_seq_len=32, max_new_tokens=8))
 monitor = Monitor(profiler, update_on_miss=False)
 for b in batches:
-    res = engine.run_batch(b, true_lens={r.rid: r.true_output_len
-                                         for r in b.requests})
+    res = engine.run_continuous(list(b.requests))
     for r in b.requests:
         monitor.observe(r)
     print(f"batch of {len(b)}: prefill {res.prefill_s*1e3:.1f} ms, "

@@ -1,7 +1,8 @@
 """End-to-end serving driver (the paper's kind of system): a reduced
-llama-family model serves a batched request stream twice — paper-faithful
-padded batching composed by SLO-ODBS, then beyond-paper continuous batching —
-and reports latency / throughput / token-identity between the two.
+llama-family model serves a request stream twice on the paged engine — the
+paper's SLO-ODBS batches, one batch after another, then the whole stream
+continuously in arrival order — and reports latency / throughput /
+token-identity between the two.
 
 Run: PYTHONPATH=src python examples/serving_e2e.py
 """
@@ -16,13 +17,13 @@ from repro.core import (LengthPredictor, ResourceProfiler, SchedulerConfig,
 from repro.core.profiler import PredictorConfig
 from repro.data.workload import WorkloadConfig, gen_requests, train_pairs
 from repro.models import api
-from repro.serving import EngineConfig, InferenceEngine
+from repro.serving import PagedEngine, PagedEngineConfig
 
 cfg = get_config("smollm-135m").reduced()
 params = api.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
-engine = InferenceEngine(cfg, params,
-                         EngineConfig(max_batch=4, cache_len=64,
-                                      max_new_tokens=16))
+engine = PagedEngine(cfg, params, PagedEngineConfig(
+    max_batch=4, block_size=8, n_blocks=64, max_seq_len=64,
+    max_new_tokens=16))
 
 reqs = gen_requests(WorkloadConfig(n_requests=12, seed=3, vocab=cfg.vocab_size))
 for r in reqs:
@@ -36,18 +37,17 @@ pred.fit(toks, lens, epochs=8)
 prof = ResourceProfiler(pred, cfg)
 prof.profile(reqs)
 
-# --- paper mode: SLO-ODBS padded batches ------------------------------------
+# --- paper mode: SLO-ODBS batches, served one after another ------------------
 t0 = time.perf_counter()
-padded_out = {}
+batched_out = {}
 total_steps = 0
 for b in slo_odbs(reqs, SchedulerConfig(max_batch=4)):
-    res = engine.run_batch(b, true_lens={r.rid: r.true_output_len
-                                         for r in b.requests})
-    padded_out.update(res.outputs)
+    res = engine.run_continuous(list(b.requests))
+    batched_out.update(res.outputs)
     total_steps += res.steps
-t_padded = time.perf_counter() - t0
-tok_padded = sum(len(v) for v in padded_out.values())
-print(f"[padded/SLO-ODBS]  {tok_padded} tokens in {t_padded:.2f}s "
+t_batched = time.perf_counter() - t0
+tok_batched = sum(len(v) for v in batched_out.values())
+print(f"[SLO-ODBS batches] {tok_batched} tokens in {t_batched:.2f}s "
       f"({total_steps} decode iterations)")
 
 # --- beyond-paper: continuous batching ---------------------------------------
@@ -58,7 +58,7 @@ tok_cont = sum(len(v) for v in res_c.outputs.values())
 print(f"[continuous]       {tok_cont} tokens in {t_cont:.2f}s "
       f"({res_c.steps} decode iterations)")
 
-same = all(padded_out[r.rid] == res_c.outputs[r.rid] for r in reqs)
+same = all(batched_out[r.rid] == res_c.outputs[r.rid] for r in reqs)
 print(f"token-identical outputs across modes: {same}")
 print(f"decode-iteration reduction from continuous batching: "
       f"{total_steps} -> {res_c.steps}")

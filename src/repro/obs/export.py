@@ -10,11 +10,10 @@ Two output formats, each with a validator the tests and ci.sh gate on:
   chrome://tracing or https://ui.perfetto.dev.
 
 * **Metrics JSON** (``metrics_payload`` / ``validate_metrics``): the one
-  schema shared by ``benchmarks/common.persist`` (``BENCH_<name>.json``)
-  and ``serve.py --metrics-json`` — same top-level latency / throughput /
-  utilization / SLO fields, plus the monitor's metrics (histogram quantile
-  blocks included) so a benchmark artifact and a serve run are directly
-  comparable.
+  schema ``serve.py --metrics-json`` and ``simulate.py --metrics-json``
+  write — top-level latency / throughput / utilization / SLO fields, plus
+  the monitor's metrics (histogram quantile blocks included) so a live run
+  and a simulated one are directly comparable.
 """
 from __future__ import annotations
 
@@ -137,11 +136,11 @@ def metrics_payload(name: str, *, latency_s=None, p99_latency_s=None,
                     monitor: Optional[dict] = None,
                     profile: Optional[dict] = None,
                     extra: Optional[dict] = None) -> dict:
-    """The shared metrics schema: identical top-level fields whether the
-    producer is a benchmark harness (``common.persist``) or a serve run
-    (``--metrics-json``).  ``monitor`` carries ``Monitor.metrics()``
-    verbatim — including the per-axis histogram quantile blocks — and is
-    ``{}`` for harnesses that run without a monitor (schema v5: the
+    """The shared metrics schema: identical top-level fields whichever run
+    produces it (``--metrics-json`` of serve or simulate, a CI smoke).
+    ``monitor`` carries ``Monitor.metrics()`` verbatim — including the
+    per-axis histogram quantile blocks — and is ``{}`` for producers that
+    run without a monitor (schema v5: the
     monitor block may carry ``slo_by_key`` per-model/per-tier attainment;
     v6: also a ``faults`` block with failure/retry/brownout counters).
     ``profile`` carries ``CostProfiler.metrics()`` — coverage counters,

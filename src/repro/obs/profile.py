@@ -298,8 +298,7 @@ class CostProfiler:
         iteration emits (identical t0/dur within a phase and track).  The
         span's ``track`` is the replica the sample is attributed to.
 
-        This is the serve path's per-span hot path (gated by
-        interleave_bench's 5% profiling-overhead budget), so the
+        This is the serve path's per-span hot path, so the
         key/prediction computation of ``observe_decode``/``observe_prefill``
         is inlined here rather than called through them."""
         if ev.ph != "X":

@@ -5,11 +5,11 @@ Used by three consumers:
   1. the HELR-mesh deployer (pick the feasible min-time plan),
   2. the discrete-event cluster simulator (latency model for the paper's
      experiments),
-  3. the roofline table (EXPERIMENTS.md §Roofline) — where it is the primary
-     FLOP/byte source, validated against compiled-HLO cost_analysis() on
-     reduced configs (tests/test_cost_model.py); raw HLO numbers undercount
-     lax.scan bodies (counted once, not × trip count), which is documented
-     there.
+  3. the roofline terms of the dry-run and the hillclimb (EXPERIMENTS.md
+     §Roofline) — where it is the primary FLOP/byte source, validated
+     against compiled-HLO cost_analysis() on reduced configs
+     (tests/test_cost_model.py); raw HLO numbers undercount lax.scan
+     bodies (counted once, not × trip count), which is documented there.
 
 Conventions: bf16 params/activations (2 bytes), fp32 accumulation; causal
 attention counted at the full s² (matching XLA, which computes masked blocks

@@ -1,4 +1,3 @@
-from repro.serving.engine import BatchResult, EngineConfig, InferenceEngine  # noqa: F401
 from repro.serving.kv_cache import (BlockAllocator, PagedKVCache,  # noqa: F401
                                     PagedKVConfig)
 from repro.serving.paged_engine import (PagedBatchResult,  # noqa: F401

@@ -17,7 +17,7 @@ Host-side block allocator + device-side paged layout:
 ``gather`` materializes a sequence's contiguous view for the (non-paged)
 decode kernels; the paged Pallas decode kernel (kernels.paged_attention)
 reads through the block table directly, and serving.paged_engine drives it —
-see EXPERIMENTS.md §Perf for the design record and bench numbers.
+see EXPERIMENTS.md §Perf for the design record.
 """
 from __future__ import annotations
 

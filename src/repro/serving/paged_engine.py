@@ -1,8 +1,8 @@
-"""Paged continuous-batching inference engine.
+"""Paged continuous-batching inference engine, the one served engine.
 
-``InferenceEngine.run_continuous`` re-prefills the *entire* slot set on every
-admission wave (padded wave prefill, all decode state discarded); this engine
-is the production-shaped alternative the paper's batch shaping composes with:
+Greedy decoding, token-identical to the dense greedy reference (each request
+alone: ``api.prefill`` on a batch of one, then ``api.decode_step``), built
+around paged KV and per-prompt admission:
 
 * KV lives in fixed-size physical blocks (``kernels.paged_attention``); each
   slot owns an ordered block list from a single ``BlockAllocator`` — O(1)
@@ -52,7 +52,6 @@ from repro.core.types import Request
 from repro.kernels.paged_attention.paged_attention import (bucket_nb,
                                                           live_pages)
 from repro.models import api
-from repro.serving.engine import BatchResult
 from repro.obs.trace import (NULL_TRACER, ROW_QUEUE, LatencyBreakdown,
                              Tracer, phase, slot_row)
 from repro.serving.kv_cache import BlockAllocator
@@ -120,7 +119,11 @@ class PagedEngineConfig:
 
 
 @dataclass
-class PagedBatchResult(BatchResult):
+class PagedBatchResult:
+    outputs: dict[int, list[int]] = field(default_factory=dict)  # rid -> tokens
+    prefill_s: float = 0.0
+    decode_s: float = 0.0
+    steps: int = 0
     prefill_tokens: int = 0        # tokens actually prefilled (block-padded)
     admission_waves: int = 0
     peak_blocks: int = 0           # high-water mark of live blocks
@@ -142,7 +145,7 @@ class PagedBatchResult(BatchResult):
     preempted_tokens: int = 0      # generated tokens whose K/V was recomputed
     inter_token_s: list = field(default_factory=list)
     #   wall-clock gaps between consecutive decode emissions per slot — the
-    #   decode-stall distribution interleave_bench takes its p99 over (a
+    #   decode-stall distribution ``p99_inter_token_s`` reads (a
     #   speculative iteration emitting n tokens spreads its gap over the n)
     # --- speculative decoding (spec_tokens > 0) ---
     drafted_tokens: int = 0        # draft positions scored by verify passes
@@ -285,7 +288,7 @@ class PagedDecodeState:
 
 class PagedEngine:
     """Continuous batching over paged KV blocks.  Greedy decoding, token-
-    identical to ``InferenceEngine.run_batch`` for the same requests (the
+    identical to the dense greedy reference for the same requests (the
     decode math only differs in cache addressing; chunked prefill and
     preempt-and-recompute replay the same math, so they preserve it too)."""
 

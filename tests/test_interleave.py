@@ -7,8 +7,9 @@ import copy
 import numpy as np
 import pytest
 
+from conftest import dense_greedy
 from repro.configs import get_config
-from repro.core.types import Batch, Request
+from repro.core.types import Request
 from repro.obs import Tracer
 from repro.serving import PagedEngine, PagedEngineConfig, kv_block_bytes
 
@@ -220,23 +221,18 @@ def test_single_block_budget_still_serves(model):
 
 def test_preemption_recompute_token_identity(model):
     """Block pressure + preempt: the slack-most resident is evicted for a
-    tighter arrival, requeued, recomputed — outputs identical to the padded
+    tighter arrival, requeued, recomputed — outputs identical to the dense
     reference, and the preemption is visible in the result gauges."""
-    from repro.serving import EngineConfig, InferenceEngine
     cfg, params = model
     reqs = [_req(0, [3] * 8, out=8, slo=1000.0),
             _req(1, [5] * 8, out=4, slo=0.001)]
-    ref = InferenceEngine(cfg, params,
-                          EngineConfig(max_batch=2, cache_len=32,
-                                       max_new_tokens=8)).run_batch(
-        Batch(requests=[copy.copy(r) for r in reqs]),
-        true_lens={r.rid: r.true_output_len for r in reqs})
+    ref = dense_greedy(cfg, params, reqs, 8)
     res = _serve(cfg, params, reqs, max_batch=2, n_blocks=4,
                  max_seq_len=32, max_new_tokens=8, preempt=True)
     assert res.preemptions >= 1
     assert res.preempted_tokens >= 1
     for r in reqs:
-        assert res.outputs[r.rid] == ref.outputs[r.rid], r.rid
+        assert res.outputs[r.rid] == ref[r.rid], r.rid
 
 
 def test_no_preempt_blocks_instead(model):

@@ -1,35 +1,35 @@
-"""Paged serving runtime: token equivalence with the padded engine, true
-continuous admission (prefill proportional to prompts, never to slots),
+"""Paged serving runtime: token equivalence with the dense greedy reference,
+true continuous admission (prefill proportional to prompts, never to slots),
 allocator exhaustion/backpressure, and the batched PagedKVCache scatter."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import dense_greedy
 from repro.configs import get_config
-from repro.core.types import Batch
 from repro.data.workload import WorkloadConfig, gen_requests
 from repro.models import api
-from repro.serving import (EngineConfig, InferenceEngine, PagedEngine,
-                           PagedEngineConfig)
+from repro.serving import PagedEngine, PagedEngineConfig
 from repro.serving.kv_cache import (BlockAllocator, PagedKVCache,
                                     PagedKVConfig)
 
 BS = 8          # KV block size used throughout
 
 
-@pytest.fixture(scope="module")
-def engines():
-    cfg = get_config("smollm-135m").reduced()
-    params = api.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
-    eng = InferenceEngine(cfg, params,
-                          EngineConfig(max_batch=4, cache_len=64,
-                                       max_new_tokens=12))
-    peng = PagedEngine(cfg, params,
+def _paged(cfg, params):
+    return PagedEngine(cfg, params,
                        PagedEngineConfig(max_batch=4, block_size=BS,
                                          n_blocks=64, max_seq_len=64,
                                          max_new_tokens=12))
-    return cfg, eng, peng
+
+
+@pytest.fixture(scope="module")
+def engines():
+    """(cfg, params, paged engine) of the reduced smollm-135m."""
+    cfg = get_config("smollm-135m").reduced()
+    params = api.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    return cfg, params, _paged(cfg, params)
 
 
 def _reqs(cfg, n=6, out_max=8, seed=5):
@@ -46,23 +46,26 @@ def _block_padded(n):
     return -(-n // BS) * BS
 
 
-def test_paged_matches_padded_tokens(engines):
+@pytest.mark.parametrize("arch", ["smollm-135m", "qwen2-1.5b",
+                                  "chatglm2-6b", "qwen2-vl-7b"])
+def test_paged_matches_dense_reference(arch):
     """Greedy paged continuous batching emits the exact token streams of the
-    paper-mode padded batch for the same requests."""
-    cfg, eng, peng = engines
+    dense greedy reference (each request decoded alone) for the same
+    requests, on every paged-compatible attention family."""
+    cfg = get_config(arch).reduced()
+    params = api.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
     reqs = _reqs(cfg, 4)
-    tl = {r.rid: r.true_output_len for r in reqs}
-    res_p = eng.run_batch(Batch(requests=reqs), true_lens=tl)
-    res_c = peng.run_continuous(reqs)
+    res = _paged(cfg, params).run_continuous(reqs)
+    ref = dense_greedy(cfg, params, reqs, 12)
     for r in reqs:
-        assert res_p.outputs[r.rid] == res_c.outputs[r.rid], r.rid
+        assert res.outputs[r.rid] == ref[r.rid], r.rid
 
 
 def test_paged_prefill_proportional_to_prompts(engines):
     """No full-slot re-prefill: admitted prompts are prefilled individually,
     so prefill token count is exactly the (block-padded) sum of prompt
     lengths — independent of how many admission waves slot recycling takes."""
-    cfg, eng, peng = engines
+    cfg, _, peng = engines
     reqs = _reqs(cfg, 7)               # > max_batch=4 -> slots must recycle
     res = peng.run_continuous(reqs)
     assert set(res.outputs) == {r.rid for r in reqs}
@@ -75,23 +78,21 @@ def test_paged_prefill_proportional_to_prompts(engines):
 
 def test_paged_recycled_slots_match_fresh_padded_decode(engines):
     """Sequences admitted into recycled slots (residents mid-decode) must
-    still decode exactly as a fresh padded batch would."""
-    cfg, eng, peng = engines
+    still decode exactly as each would decode alone."""
+    cfg, params, peng = engines
     reqs = _reqs(cfg, 7)
     res_c = peng.run_continuous(reqs)
     late = reqs[4:]
-    res_p = eng.run_batch(Batch(requests=late),
-                          true_lens={r.rid: r.true_output_len for r in late})
+    ref = dense_greedy(cfg, params, late, 12)
     for r in late:
-        assert res_p.outputs[r.rid] == res_c.outputs[r.rid], r.rid
+        assert ref[r.rid] == res_c.outputs[r.rid], r.rid
 
 
 def test_block_backpressure_defers_admission(engines):
     """A pool that cannot hold all requests at once admits in waves gated on
     BlockAllocator.can_alloc, never exceeds the pool, and still serves
     everything."""
-    cfg, eng, _ = engines
-    params = eng.params
+    cfg, params, _ = engines
     # worst case per request: ceil((10 + 12)/8) = 3 blocks; pool of 7 usable
     # blocks fits two residents + the null block, not four
     pcfg = PagedEngineConfig(max_batch=4, block_size=BS, n_blocks=8,
@@ -104,21 +105,20 @@ def test_block_backpressure_defers_admission(engines):
         assert len(res.outputs[r.rid]) == min(r.true_output_len, 12)
     assert res.admission_waves >= 3          # backpressure forced deferral
     assert res.peak_blocks <= pcfg.n_blocks - 1
-    # outputs unchanged vs the padded engine
-    res_p = eng.run_batch(Batch(requests=reqs),
-                          true_lens={r.rid: r.true_output_len for r in reqs})
+    # outputs unchanged vs the dense reference
+    ref = dense_greedy(cfg, params, reqs, 12)
     for r in reqs:
-        assert res_p.outputs[r.rid] == res.outputs[r.rid], r.rid
+        assert ref[r.rid] == res.outputs[r.rid], r.rid
 
 
 def test_single_token_request_admitted_mid_run(engines):
     """A request whose entire output is its prefill token (stop count 1),
     admitted into a recycled slot mid-run, must not receive an extra decode
     token before the finish scan sees it."""
-    cfg, eng, _ = engines
+    cfg, params, _ = engines
     pcfg = PagedEngineConfig(max_batch=2, block_size=BS, n_blocks=32,
                              max_seq_len=64, max_new_tokens=12)
-    peng = PagedEngine(cfg, eng.params, pcfg)
+    peng = PagedEngine(cfg, params, pcfg)
     reqs = _reqs(cfg, 3)
     reqs[0].true_output_len = 2
     reqs[1].true_output_len = 6
@@ -126,10 +126,9 @@ def test_single_token_request_admitted_mid_run(engines):
     res = peng.run_continuous(reqs)
     for r in reqs:
         assert len(res.outputs[r.rid]) == r.true_output_len, r.rid
-    res_p = eng.run_batch(Batch(requests=reqs),
-                          true_lens={r.rid: r.true_output_len for r in reqs})
+    ref = dense_greedy(cfg, params, reqs, 12)
     for r in reqs:
-        assert res_p.outputs[r.rid] == res.outputs[r.rid], r.rid
+        assert ref[r.rid] == res.outputs[r.rid], r.rid
 
 
 def test_kv_page_counters_sum_the_kernels_reads(engines):
@@ -138,10 +137,10 @@ def test_kv_page_counters_sum_the_kernels_reads(engines):
     pages holding positions 0..kv (the new token's included), a free slot
     its null block.  ``kv_pages_table`` is what a walk of the whole bucketed
     table reads."""
-    cfg, eng, _ = engines
+    cfg, params, _ = engines
     pcfg = PagedEngineConfig(max_batch=2, block_size=BS, n_blocks=32,
                              max_seq_len=64, max_new_tokens=12)
-    peng = PagedEngine(cfg, eng.params, pcfg)
+    peng = PagedEngine(cfg, params, pcfg)
     reqs = _reqs(cfg, 2)
     for r, n in zip(reqs, (10, 5)):
         r.tokens, r.input_len = r.tokens[:n], n
@@ -159,10 +158,10 @@ def test_kv_page_counters_sum_the_kernels_reads(engines):
 
 def test_request_larger_than_pool_rejected(engines):
     from repro.core.types import Request
-    cfg, eng, _ = engines
+    cfg, params, _ = engines
     pcfg = PagedEngineConfig(max_batch=2, block_size=BS, n_blocks=3,
                              max_seq_len=64, max_new_tokens=12)
-    peng = PagedEngine(cfg, eng.params, pcfg)
+    peng = PagedEngine(cfg, params, pcfg)
     # worst case ceil((30 + 12)/8) = 6 blocks > the 2 usable in the pool
     big = Request(rid=0, tokens=[1] * 30, input_len=30, slo=10.0,
                   arrival=0.0, true_output_len=12)

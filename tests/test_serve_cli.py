@@ -1,9 +1,10 @@
 """serve.main as a library entry point: argv in, served outputs and the
-engines out; the compile-cache placement rule; replica placement."""
+engines out; the compile-cache placement rule; replica placement; and
+simulate.main, the simulated cluster's entry point."""
 import jax
 import pytest
 
-from repro.launch import serve
+from repro.launch import serve, simulate
 
 
 @pytest.fixture
@@ -14,7 +15,7 @@ def cache_env(monkeypatch, tmp_path):
 
 
 def test_main_serves_paged_from_argv(cache_env):
-    res = serve.main(["--reduced", "--paged", "--requests", "3",
+    res = serve.main(["--reduced", "--requests", "3",
                       "--max-new", "4"])
     outs = res["outputs"]
     assert sorted(outs) == [0, 1, 2]
@@ -24,13 +25,30 @@ def test_main_serves_paged_from_argv(cache_env):
 
 
 def test_replicas_placed_round_robin_over_devices(cache_env):
-    res = serve.main(["--reduced", "--paged", "--replicas", "2",
+    res = serve.main(["--reduced", "--replicas", "2",
                       "--router", "round_robin", "--requests", "4",
                       "--max-new", "3"])
     devs = jax.devices()
     assert [e.device for e in res["engines"]] == [devs[i % len(devs)]
                                                   for i in range(2)]
     assert len(res["outputs"]) == 4
+
+
+def test_simulate_main_serves_cluster_without_weights(cache_env,
+                                                     monkeypatch):
+    """The simulated cluster prices every request on LatencyModel replicas
+    and builds no model weights: each request finishes or is shed."""
+    from repro.models import api
+
+    def no_weights(*a, **kw):
+        raise AssertionError("the simulator initialised model weights")
+
+    monkeypatch.setattr(api, "init_params", no_weights)
+    res = simulate.main(["--reduced", "--replicas", "2", "--router",
+                         "slo_aware", "--requests", "8"])
+    assert res.requests
+    assert len(res.finished) + len(res.shed) == len(res.requests)
+    assert res.peak_replicas == 2
 
 
 def test_compile_cache_dir_from_env_or_repo(monkeypatch, tmp_path):

@@ -1,11 +1,7 @@
-"""Serving runtime: padded vs continuous engine equivalence on real JAX
-models, simulator end-to-end sanity, and the UELLM-vs-baseline orderings the
-paper claims (directionally, on the simulated paper cluster)."""
+"""Serving simulator: end-to-end sanity and the UELLM-vs-baseline orderings
+the paper claims (directionally, on the simulated paper cluster)."""
 import copy
 
-import jax
-import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from repro.configs import get_config
@@ -13,62 +9,9 @@ from repro.core import (LengthPredictor, Monitor, ResourceProfiler, bgs,
                         get_scheduler, helr)
 from repro.core.profiler import PredictorConfig
 from repro.core.scheduler import SchedulerConfig
-from repro.core.types import Batch, DeviceNode
+from repro.core.types import DeviceNode
 from repro.data.workload import WorkloadConfig, gen_requests, train_pairs
-from repro.models import api
-from repro.serving import (EngineConfig, InferenceEngine, LatencyModel,
-                           morphling_deploy_overhead, paper_cluster, simulate)
-
-
-@pytest.fixture(scope="module")
-def small_engine():
-    cfg = get_config("smollm-135m").reduced()
-    params = api.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
-    eng = InferenceEngine(cfg, params,
-                          EngineConfig(max_batch=4, cache_len=64,
-                                       max_new_tokens=12))
-    return cfg, eng
-
-
-def _reqs(cfg, n=6, out_max=8):
-    reqs = gen_requests(WorkloadConfig(n_requests=n, seed=5,
-                                       vocab=cfg.vocab_size))
-    for r in reqs:
-        r.tokens = [t % cfg.vocab_size for t in r.tokens[:10]]
-        r.input_len = len(r.tokens)
-        r.true_output_len = min(r.true_output_len % out_max + 1, out_max)
-    return reqs
-
-
-def test_padded_engine_runs(small_engine):
-    cfg, eng = small_engine
-    reqs = _reqs(cfg, 4)
-    res = eng.run_batch(Batch(requests=reqs),
-                        true_lens={r.rid: r.true_output_len for r in reqs})
-    for r in reqs:
-        assert len(res.outputs[r.rid]) == r.true_output_len
-    assert res.steps == max(r.true_output_len for r in reqs)
-
-
-def test_continuous_matches_padded_tokens(small_engine):
-    """Same greedy model -> identical generated tokens under padded and
-    continuous batching for requests admitted in the first wave."""
-    cfg, eng = small_engine
-    reqs = _reqs(cfg, 4)
-    tl = {r.rid: r.true_output_len for r in reqs}
-    res_p = eng.run_batch(Batch(requests=reqs), true_lens=tl)
-    res_c = eng.run_continuous(reqs)
-    for r in reqs:
-        assert res_p.outputs[r.rid] == res_c.outputs[r.rid], r.rid
-
-
-def test_continuous_slot_reuse(small_engine):
-    cfg, eng = small_engine
-    reqs = _reqs(cfg, 7)           # > max_batch=4 -> slots must recycle
-    res = eng.run_continuous(reqs)
-    assert set(res.outputs) == {r.rid for r in reqs}
-    for r in reqs:
-        assert len(res.outputs[r.rid]) == min(r.true_output_len, 12)
+from repro.serving import morphling_deploy_overhead, simulate
 
 
 # ----------------------------------------------------------------- simulator

@@ -9,12 +9,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import dense_greedy
 from repro.configs import get_config
 from repro.core.scheduler import SchedulerConfig, slo_odbs, spec_speedup
-from repro.core.types import Batch, Request
+from repro.core.types import Request
 from repro.models import api
-from repro.serving import (EngineConfig, InferenceEngine, ModelDrafter,
-                           NGramDrafter, PagedEngine, PagedEngineConfig)
+from repro.serving import (ModelDrafter, NGramDrafter, PagedEngine,
+                           PagedEngineConfig)
 from repro.serving.simulator import simulate_continuous
 
 
@@ -41,10 +42,7 @@ def _reqs(cfg, n=6, out_lo=4, out_hi=12, seed=3, rep=True):
 
 
 def _ref(cfg, params, reqs, max_new=12):
-    eng = InferenceEngine(cfg, params, EngineConfig(
-        max_batch=len(reqs), cache_len=64, max_new_tokens=max_new))
-    return eng.run_batch(Batch(requests=[copy.copy(r) for r in reqs]),
-                         true_lens={r.rid: r.true_output_len for r in reqs})
+    return dense_greedy(cfg, params, reqs, max_new)
 
 
 # ------------------------------------------------------------------ drafters
@@ -87,7 +85,7 @@ def test_model_drafter_self_draft_matches_target(model):
         max_new_tokens=12, spec_tokens=4),
         drafter=ModelDrafter(cfg, params))
     res = eng.run_continuous([copy.copy(r) for r in reqs])
-    assert all(res.outputs[r.rid] == ref.outputs[r.rid] for r in reqs)
+    assert all(res.outputs[r.rid] == ref[r.rid] for r in reqs)
     assert res.acceptance_rate == 1.0
     assert res.drafted_tokens > 0
 
@@ -103,7 +101,7 @@ def test_spec_outputs_token_identical(model, spec_tokens):
         max_batch=4, block_size=8, n_blocks=64, max_seq_len=64,
         max_new_tokens=12, spec_tokens=spec_tokens))
     res = eng.run_continuous([copy.copy(r) for r in reqs])
-    assert all(res.outputs[r.rid] == ref.outputs[r.rid] for r in reqs)
+    assert all(res.outputs[r.rid] == ref[r.rid] for r in reqs)
     assert res.drafted_tokens >= res.accepted_tokens >= 0
 
 
@@ -117,7 +115,7 @@ def test_spec_identical_on_adversarial_random_prompts(model):
         max_batch=4, block_size=8, n_blocks=64, max_seq_len=64,
         max_new_tokens=12, spec_tokens=4))
     res = eng.run_continuous([copy.copy(r) for r in reqs])
-    assert all(res.outputs[r.rid] == ref.outputs[r.rid] for r in reqs)
+    assert all(res.outputs[r.rid] == ref[r.rid] for r in reqs)
 
 
 def test_spec_composes_with_chunked_prefill_preempt_prefix(model):
@@ -139,7 +137,7 @@ def test_spec_composes_with_chunked_prefill_preempt_prefix(model):
         max_new_tokens=10, spec_tokens=3, prefix_cache=True,
         chunk_tokens=8, preempt=True, admit_lookahead=2))
     res = eng.run_continuous([copy.copy(r) for r in reqs])
-    assert all(res.outputs[r.rid] == ref.outputs[r.rid] for r in reqs)
+    assert all(res.outputs[r.rid] == ref[r.rid] for r in reqs)
     assert res.prefill_chunks > len(reqs)          # chunking engaged
     assert res.prefix_hits > 0                     # sharing engaged
 
@@ -159,7 +157,7 @@ def test_spec_under_forced_preemption(model):
         max_new_tokens=8, chunk_tokens=8, preempt=True, spec_tokens=3))
     res = eng.run_continuous([copy.copy(r) for r in reqs])
     assert res.preemptions >= 1
-    assert all(res.outputs[r.rid] == ref.outputs[r.rid] for r in reqs)
+    assert all(res.outputs[r.rid] == ref[r.rid] for r in reqs)
 
 
 def test_spec_rejection_rolls_back_blocks(model):
@@ -184,7 +182,7 @@ def test_spec_rejection_rolls_back_blocks(model):
         max_batch=3, block_size=4, n_blocks=96, max_seq_len=64,
         max_new_tokens=12, spec_tokens=8), drafter=WrongDrafter())
     res = eng.run_continuous([copy.copy(r) for r in reqs])
-    assert all(res.outputs[r.rid] == ref.outputs[r.rid] for r in reqs)
+    assert all(res.outputs[r.rid] == ref[r.rid] for r in reqs)
     assert res.accepted_tokens == 0
     assert res.drafted_tokens > 0
     assert res.spec_rolled_blocks > 0

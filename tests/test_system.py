@@ -1,5 +1,5 @@
 """End-to-end behaviour of the UELLM system: the full pipeline
-(workload -> profiler -> SLO-ODBS -> real JAX engine) produces every answer,
+(workload -> profiler -> SLO-ODBS -> paged JAX engine) produces every answer,
 and a short training run on the reduced demo model actually learns."""
 import copy
 
@@ -13,7 +13,7 @@ from repro.core import (LengthPredictor, Monitor, ResourceProfiler,
 from repro.core.profiler import PredictorConfig
 from repro.data.workload import WorkloadConfig, gen_requests, train_pairs
 from repro.models import api
-from repro.serving import EngineConfig, InferenceEngine
+from repro.serving import PagedEngine, PagedEngineConfig
 from repro.training import OptConfig, TrainConfig, init_training, make_train_step
 
 
@@ -22,9 +22,10 @@ def test_uellm_pipeline_end_to_end():
     request gets exactly its answer; the monitor sees every completion."""
     cfg = get_config("smollm-135m").reduced()
     params = api.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
-    engine = InferenceEngine(cfg, params,
-                             EngineConfig(max_batch=8, cache_len=48,
-                                          max_new_tokens=8))
+    engine = PagedEngine(cfg, params,
+                         PagedEngineConfig(max_batch=4, block_size=8,
+                                           n_blocks=32, max_seq_len=48,
+                                           max_new_tokens=8))
     pred = LengthPredictor(PredictorConfig(vocab=cfg.vocab_size, max_len=8,
                                            n_buckets=4), seed=0)
     prof = ResourceProfiler(pred, cfg)
@@ -42,8 +43,7 @@ def test_uellm_pipeline_end_to_end():
 
     outputs = {}
     for b in batches:
-        res = engine.run_batch(b, true_lens={r.rid: r.true_output_len
-                                             for r in b.requests})
+        res = engine.run_continuous(list(b.requests))
         outputs.update(res.outputs)
         for r in b.requests:
             mon.observe(r)
